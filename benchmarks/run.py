@@ -226,14 +226,18 @@ def main() -> None:
                  f"choose from {list(sections)}")
     only = args.sections or list(sections)
     print("name,us_per_call,derived")
+    failed = []
     for key in only:
         fn, takes_seed = sections[key]
         try:
             rows = fn(seed=args.seed) if takes_seed else fn()
             for name, us, derived in rows:
                 print(f"{name},{us:.1f},{derived}")
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other sections, then fail
+            failed.append(key)
             print(f"{key}/ERROR,0,{type(e).__name__}: {e}")
+    if failed:
+        sys.exit(f"section(s) raised: {failed}")
 
 
 if __name__ == "__main__":

@@ -29,9 +29,14 @@ def main():
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=16)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="--no-reduced serves the published widths")
     args = ap.parse_args()
 
-    cfg = reduced(REGISTRY[args.arch])
+    cfg = REGISTRY[args.arch]
+    if args.reduced:
+        cfg = reduced(cfg)
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     rng = np.random.default_rng(0)

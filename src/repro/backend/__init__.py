@@ -1,8 +1,8 @@
 """Version-portability + kernel-dispatch subsystem.
 
-``repro.backend.compat``   — the ONLY place jax version differences live
-                             (pltpu symbols, mesh/AbstractMesh/shard_map
-                             API splits; supported range 0.4.37 — 0.7.x).
+``repro.backend.compat``   — the ONLY place that touches jax APIs which
+                             move between versions (pltpu symbols, meshes,
+                             shard_map, varying axes; jax 0.9.0).
 ``repro.backend.dispatch`` — the kernel-dispatch front door selecting
                              Pallas-TPU / Pallas-interpret / jnp-reference
                              per detected backend (``REPRO_KERNELS``

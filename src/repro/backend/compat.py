@@ -1,28 +1,19 @@
-"""jax version-portability layer (supported range: 0.4.37 — 0.7.x).
+"""jax surface used by the repo, pinned to the installed jax 0.9.0.
 
-Every jax API whose surface moved between 0.4.x and 0.7.x is shimmed HERE
-and nowhere else: the rest of the repo imports these helpers and never
-probes ``jax.__version__``, ``hasattr(jax, ...)``, or constructs
-``pltpu.*`` objects directly.  This is what lets the SSR latency-throughput
-explorer retarget backends (the paper's §6 portability argument): the
-execution layer is decoupled from any single jax vintage, so the same code
-is green on CPU-only jax 0.4.37 and lights up unchanged on TPU 0.7.x.
+The rest of the repo imports these helpers instead of reaching into
+``jax.experimental.pallas.tpu``, ``jax.shard_map`` or the mesh
+constructors directly, so a future jax move touches this file only.
+There is one branch per surface: the API of jax 0.9.0.
 
-Shimmed surfaces:
-  * ``pltpu.TPUCompilerParams`` (0.4.x)  vs  ``pltpu.CompilerParams`` (0.7)
-  * Pallas TPU memory spaces (``VMEM``/``SMEM``/``ANY`` scratch shapes)
-  * ``jax.sharding.AxisType`` + ``jax.make_mesh(..., axis_types=...)``
-    (absent on 0.4.x — plain ``jax.make_mesh``/``mesh_utils`` fallback)
-  * ``jax.set_mesh`` / ``jax.sharding.use_mesh``  vs  ``with mesh:``
-  * ``AbstractMesh(sizes, names)``  vs  ``AbstractMesh(((name, size), ...))``
-  * ``jax.shard_map(..., axis_names=...)`` (partial-manual)  vs
-    ``jax.experimental.shard_map.shard_map`` (full-manual fallback: 0.4.x
-    partial-auto is NotImplemented eagerly and miscompiles under SPMD)
-  * ``lax.pcast(..., to="varying")`` (no-op before the varying-axes rework)
+Surfaces:
+  * ``pltpu.CompilerParams`` and the Pallas TPU memory spaces
+  * ``jax.make_mesh`` / ``Mesh`` with explicit ``AxisType.Auto`` axes
+  * ``jax.set_mesh`` as the ambient-mesh context
+  * ``jax.shard_map(..., axis_names=...)`` (partial-manual)
+  * ``lax.pcast(..., to="varying")`` and the carried varying axes
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 from typing import Optional, Sequence, Tuple
 
@@ -30,7 +21,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
-SUPPORTED_RANGE = ("0.4.37", "0.7")
+SUPPORTED_RANGE = ("0.9.0", "0.9.0")
 
 
 def jax_version() -> Tuple[int, ...]:
@@ -73,13 +64,10 @@ def _pltpu():
 
 
 def tpu_compiler_params(*, dimension_semantics=None, **kwargs):
-    """``pltpu.CompilerParams`` (jax>=0.7) or ``pltpu.TPUCompilerParams``
-    (jax 0.4.x–0.6.x) — whichever the installed jax provides."""
-    mod = _pltpu()
-    cls = getattr(mod, "CompilerParams", None) or mod.TPUCompilerParams
+    """``pltpu.CompilerParams`` for ``pallas_call(compiler_params=...)``."""
     if dimension_semantics is not None:
         kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    return cls(**kwargs)
+    return _pltpu().CompilerParams(**kwargs)
 
 
 def vmem_scratch(shape, dtype):
@@ -109,67 +97,31 @@ def prefetch_grid_spec(*, num_scalar_prefetch, grid, in_specs, out_specs,
 # ---------------------------------------------------------------------------
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """Device mesh with explicit-Auto axis types where the API exists
-    (jax>=0.7 GSPMD propagation + constraints), plain mesh elsewhere."""
-    shape = tuple(shape)
+    """Device mesh over all devices with explicit-Auto axis types (GSPMD
+    propagation + sharding constraints)."""
     axes = tuple(axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None and hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # make_mesh without axis_types kwarg
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils  # pragma: no cover (jax<0.4.35)
-    devices = mesh_utils.create_device_mesh(shape)
-    return Mesh(devices, axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_on(devices, axes) -> Mesh:
     """Mesh over an explicit device array (subset meshes — e.g. the
     plan-mesh's ``S * width`` devices out of a larger pod), with the same
-    explicit-Auto axis types ``make_mesh`` applies where the API exists."""
+    explicit-Auto axis types as ``make_mesh``."""
     arr = np.asarray(devices)
-    axes = tuple(axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return Mesh(arr, axes, axis_types=(axis_type.Auto,) * arr.ndim)
-        except TypeError:  # Mesh without axis_types kwarg
-            pass
-    return Mesh(arr, axes)
+    return Mesh(arr, tuple(axes),
+                axis_types=(jax.sharding.AxisType.Auto,) * arr.ndim)
 
 
 def use_mesh(mesh):
-    """Context manager placing ``mesh`` in ambient context.
-
-    jax>=0.7: ``jax.set_mesh``; 0.5.x–0.6.x: ``jax.sharding.use_mesh``;
-    0.4.x: the classic ``with mesh:`` context (Mesh is its own manager)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-
-    @contextlib.contextmanager
-    def _ctx():
-        with mesh:
-            yield mesh
-    return _ctx()
+    """Context manager placing ``mesh`` in ambient context."""
+    return jax.set_mesh(mesh)
 
 
 def make_abstract_mesh(shape: Sequence[int], names: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` across the constructor split:
-    jax>=0.5 takes ``(sizes, names)``; 0.4.x takes a single
-    ``((name, size), ...)`` shape tuple."""
+    """``jax.sharding.AbstractMesh`` of ``shape`` over axes ``names``."""
     from jax.sharding import AbstractMesh
-    shape = tuple(shape)
-    names = tuple(names)
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def mesh_axis_size(mesh, name) -> int:
@@ -186,39 +138,43 @@ def mesh_axis_size(mesh, name) -> int:
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               manual_axes: Optional[frozenset] = None):
-    """Portable shard_map with a subset of axes manual.
-
-    jax>=0.7 exposes ``jax.shard_map(..., axis_names=manual_axes)`` and the
-    non-manual axes stay auto (GSPMD).  On 0.4.x partial-auto shard_map is
-    NotImplemented eagerly and miscompiles under SPMD partitioning, so the
-    fallback runs FULL-manual with ``check_rep=False``: replicated in_specs
-    become redundant per-device compute over the would-be-auto axes —
-    numerically identical, and correct on single-host CPU meshes."""
+    """``jax.shard_map`` with only ``manual_axes`` manual (all mesh axes
+    when None); the other axes stay auto (GSPMD)."""
     if manual_axes is None:
         manual_axes = frozenset(mesh.axis_names)
-    new_sm = getattr(jax, "shard_map", None)
-    if new_sm is not None:
-        try:
-            return new_sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs,
-                          axis_names=frozenset(manual_axes))
-        except TypeError:  # pragma: no cover (axis_names kwarg renamed)
-            pass
-    from jax.experimental.shard_map import shard_map as old_sm
-    return old_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset(manual_axes))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it at
+    start-up, and the cache stays there.  Otherwise it goes to one fixed
+    directory of the checkout, ``<repo>/.jax_cache`` (git-ignored): the
+    path is part of what a later run must find again, so it never comes
+    from a temporary name, a pid or the time.  Call once at program
+    start-up, never at import."""
+    import os
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        repo = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))))
+        path = os.path.join(repo, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def donating_jit(fun, *, donate_argnums=(), static_argnames=None):
-    """``jax.jit(fun, donate_argnums=...)`` that stays quiet on backends
-    where donation is unimplemented.
+    """``jax.jit(fun, donate_argnums=...)`` without the advisory
+    "Some donated buffers were not usable" warning.
 
-    CPU jax (the 0.4.x floor) cannot donate input buffers and emits a
-    "Some donated buffers were not usable" UserWarning on every call; on
-    TPU the same donation halves the peak cache footprint of the serving
-    hot loop.  Callers treat donation as a hint: every donated argument is
-    rebound to the returned value, so the suppressed warning is the only
-    backend-visible difference."""
+    Donation lets the serving hot loop update its cache in place, which
+    halves the cache's peak footprint.  Callers rebind every donated
+    argument to the returned value and never read the argument again.
+    Where XLA cannot reuse a donated buffer for an output it warns on
+    every call; that warning is silenced here."""
     import warnings
 
     kwargs = {}
@@ -239,15 +195,29 @@ def donating_jit(fun, *, donate_argnums=(), static_argnames=None):
 
 
 def pcast_varying(x, axes):
-    """``lax.pcast(x, axes, to="varying")`` where the varying-axes system
-    exists; identity on older jax (full-manual shard_map has no replication
-    tracking to inform)."""
+    """``lax.pcast(x, axes, to="varying")``: mark a value as varying over
+    the manual mesh ``axes`` (a no-op for an empty ``axes``)."""
     from jax import lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, tuple(axes), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, tuple(axes))
-    return x
+    axes = tuple(axes)
+    return lax.pcast(x, axes, to="varying") if axes else x
+
+
+def kernel_out(shape, dtype, *inputs) -> jax.ShapeDtypeStruct:
+    """``pallas_call`` output shape that varies over the manual mesh axes
+    its ``inputs`` vary over.  Under ``shard_map`` (which checks varying
+    axes) a kernel's output must say so; outside, the axes are empty."""
+    return jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                vma=frozenset(varying_axes(*inputs)))
+
+
+def varying_axes(*trees) -> Tuple[str, ...]:
+    """The manual mesh axes any leaf of ``trees`` varies over — empty
+    outside ``shard_map``.  A scan carry built from constants must start
+    with these axes when its body mixes it with such leaves."""
+    axes = set()
+    for leaf in jax.tree.leaves(trees):
+        axes |= set(getattr(jax.typeof(leaf), "vma", ()))
+    return tuple(sorted(axes))
 
 
 __all__ = [
@@ -256,5 +226,7 @@ __all__ = [
     "prefetch_grid_spec",
     "make_mesh", "make_mesh_on", "use_mesh", "make_abstract_mesh",
     "mesh_axis_size",
-    "shard_map", "pcast_varying", "PartitionSpec", "donating_jit",
+    "shard_map", "pcast_varying", "varying_axes", "kernel_out",
+    "PartitionSpec",
+    "donating_jit", "use_compile_cache",
 ]

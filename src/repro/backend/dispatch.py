@@ -13,9 +13,19 @@ Selection: ``REPRO_KERNELS`` env var forces a path ("pallas" /
 ``ref`` elsewhere.  Models, the serving engine, and the pipeline executor
 all call through here, so one flag flips the whole system between the
 fused kernels and the oracle — and the kernel sweep tests compare the two.
+
+On the ``pallas`` path a shape the kernel cannot tile raises a
+``ValueError`` naming the kernel and the shape; it never quietly runs the
+oracle.  The cases that run the oracle by design (int8 pools in the
+unfused paged kernels, paged verify on every path, attention shapes
+``use_flash`` turns away) are reported as ``ref``: every dispatch records
+the path it took into the open ``record_paths`` collectors, which is how
+the serving engine's stats name what each compiled kernel really ran.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 
 import jax.numpy as jnp
@@ -28,6 +38,43 @@ _PATHS = ("pallas", "interpret", "ref")
 _KERNELS = ("matmul", "flash_attention", "paged_attention",
             "paged_prefill_attention", "paged_verify_attention",
             "fused_paged_decode", "layernorm", "linear_scan")
+
+
+_RECORDERS: contextvars.ContextVar = contextvars.ContextVar(
+    "kernel_path_recorders", default=())
+
+
+@contextlib.contextmanager
+def record_paths(into: dict):
+    """While the block runs, add ``kernel -> {path, ...}`` to ``into`` for
+    every kernel dispatched.  Dispatch happens when a step is traced, so
+    wrap the calls that compile (a jitted step traces on first call)."""
+    token = _RECORDERS.set(_RECORDERS.get() + (into,))
+    try:
+        yield into
+    finally:
+        _RECORDERS.reset(token)
+
+
+def _took(kernel: str, path: str) -> str:
+    for rec in _RECORDERS.get():
+        rec.setdefault(kernel, set()).add(path)
+    return path
+
+
+def _check_paged_tile(kernel: str, q, k_pages, int8: bool = False):
+    """The pallas path needs (page, head_dim) blocks the TPU compiler
+    accepts: head_dim a multiple of 128 lanes, page a multiple of 8
+    sublanes (32 for int8 tiles).  Off the tile it raises; it never
+    falls back to the jnp reference."""
+    rows = 32 if int8 else 8
+    page, d = k_pages.shape[2], k_pages.shape[3]
+    if d % 128 or page % rows:
+        raise ValueError(
+            f"{kernel}: q {q.shape}, pages {k_pages.shape} are off the TPU "
+            f"tile (head_dim % 128, page % {rows}); the pallas path does "
+            f"not fall back to the jnp reference — set REPRO_KERNELS=ref "
+            f"or {kernel}=ref to run it")
 
 
 def _resolve(path: str) -> str:
@@ -77,10 +124,14 @@ def use_flash(cfg, q, k) -> bool:
     only when shapes tile cleanly to the MXU and we're not on the oracle
     path.  (The jnp fallback is itself XLA-fused on CPU.)"""
     if kernel_path("flash_attention") == "ref":
+        _took("flash_attention", "ref")
         return False
     b, s, h, d = q.shape
     t = k.shape[1]
-    return (s % 8 == 0 and t % 128 == 0 and d % 128 == 0)
+    if s % 8 == 0 and t % 128 == 0 and d % 128 == 0:
+        return True
+    _took("flash_attention", "ref")
+    return False
 
 
 def dispatch_flash_attention(q, k, v, *, q_pos, k_pos, k_valid=None,
@@ -93,7 +144,7 @@ def dispatch_flash_attention(q, k, v, *, q_pos, k_pos, k_valid=None,
     vk = jnp.swapaxes(v, 1, 2)
     if k_valid is None:
         k_valid = jnp.ones((kk.shape[2],), jnp.int32)
-    path = kernel_path("flash_attention")
+    path = _took("flash_attention", kernel_path("flash_attention"))
     if path == "ref":
         out = R.flash_attention_ref(qk, kk, vk, q_pos, k_pos, k_valid,
                                     causal=causal, window=window,
@@ -115,26 +166,28 @@ def dispatch_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                              softcap=0.0, k_scales=None, v_scales=None):
     """Decode attention through per-slot block tables over a physical
     page pool.  Layout adapter: q arrives in model layout (B, 1, H, D)
-    and leaves as (B, 1, H*D); pages are (N, P, Hkv, D); block_tables
-    (B, NB) int32 may carry out-of-range entries for unmapped logical
-    blocks (clipped here — rows past ``lengths`` are masked regardless);
-    lengths (B,) counts each slot's valid tokens.  k_scales/v_scales:
-    (N, P, Hkv) f32 dequant scales on int8 pools (None on fp).
+    and leaves as (B, 1, H*D); pages are head-major (N, Hkv, P, D);
+    block_tables (B, NB) int32 may carry out-of-range entries for
+    unmapped logical blocks (clipped here — rows past ``lengths`` are
+    masked regardless); lengths (B,) counts each slot's valid tokens.
+    k_scales/v_scales: (N, Hkv, P) f32 dequant scales on int8 pools
+    (None on fp) — int8 pools run the dequantizing jnp reference on every
+    path (recorded as ``ref``).
 
-    The pallas path additionally requires MXU-friendly tiling (head_dim
-    % 128, page % 8); off-tile shapes fall back to the jnp reference,
-    which the parity tests pin the kernel against."""
+    The pallas path requires head_dim % 128 and page % 8 and raises
+    otherwise."""
     from repro.kernels import ref as R
     b, s, h, d = q.shape
     assert s == 1, f"paged attention is a decode (one-token) path, got {s}"
-    hk = k_pages.shape[2]
+    n, hk = k_pages.shape[:2]
     qg = q[:, 0].reshape(b, hk, h // hk, d)
-    n = k_pages.shape[0]
     bt = jnp.clip(block_tables, 0, n - 1)
     path = kernel_path("paged_attention")
-    if path == "ref" or k_scales is not None \
-            or (path == "pallas" and not (d % 128 == 0
-                                          and k_pages.shape[1] % 8 == 0)):
+    if k_scales is not None:
+        path = "ref"
+    elif path == "pallas":
+        _check_paged_tile("paged_attention", q, k_pages)
+    if _took("paged_attention", path) == "ref":
         out = R.paged_attention_ref(qg, k_pages, v_pages, bt, lengths,
                                     softcap=softcap, k_scales=k_scales,
                                     v_scales=v_scales)
@@ -158,24 +211,21 @@ def dispatch_fused_paged_decode(q, k_new, v_new, k_pages, v_pages,
     ``(out (B, 1, H*D), k_pages, v_pages, k_scales, v_scales)`` — the
     pool buffers with the fresh (quantized, on int8 pools) row written.
 
-    The pallas path requires MXU-friendly tiling (head_dim % 128,
-    page % 8; int8 pools additionally page % 32 for the (32, 128) int8
-    tile); off-tile shapes fall back to the jnp reference, which the
-    parity tests pin the kernel against."""
+    The pallas path requires head_dim % 128 and page % 8 (page % 32 on
+    int8 pools, the int8 (32, 128) tile) and raises otherwise."""
     from repro.kernels import ref as R
     b, s, h, d = q.shape
     assert s == 1, f"fused paged decode is a one-token path, got {s}"
-    hk = k_pages.shape[2]
+    n, hk = k_pages.shape[:2]
     qg = q[:, 0].reshape(b, hk, h // hk, d)
     kn = k_new[:, 0]
     vn = v_new[:, 0]
-    n = k_pages.shape[0]
     bt = jnp.clip(block_tables, 0, n - 1)
-    page = k_pages.shape[1]
     path = kernel_path("fused_paged_decode")
-    tiled = d % 128 == 0 and page % 8 == 0 \
-        and (k_scales is None or page % 32 == 0)
-    if path == "ref" or (path == "pallas" and not tiled):
+    if path == "pallas":
+        _check_paged_tile("fused_paged_decode", q, k_pages,
+                          int8=k_scales is not None)
+    if _took("fused_paged_decode", path) == "ref":
         out, kp, vp, ks, vs = R.fused_paged_decode_ref(
             qg, kn, vn, k_pages, v_pages, bt, positions, theta=theta,
             softcap=softcap, k_scales=k_scales, v_scales=v_scales)
@@ -195,26 +245,27 @@ def dispatch_paged_prefill_attention(q, k_pages, v_pages, block_tables,
     the fresh chunk's K/V are already written into the pool, and every
     query attends the full mapped prefix (shared + fresh) under a causal
     mask.  Layout adapter: q arrives in model layout (B, S, H, D) and
-    leaves as (B, S, H*D); pages are (N, P, Hkv, D); block_tables (B, NB)
-    int32 may carry out-of-range entries for unmapped logical blocks
-    (clipped here, causally masked); offset () int32 is the position of
-    the first fresh query.
+    leaves as (B, S, H*D); pages are head-major (N, Hkv, P, D);
+    block_tables (B, NB) int32 may carry out-of-range entries for
+    unmapped logical blocks (clipped here, causally masked); offset ()
+    int32 is the position of the first fresh query.  int8 pools run the
+    dequantizing jnp reference on every path (recorded as ``ref``).
 
-    The pallas path additionally requires MXU-friendly tiling (head_dim
-    % 128, page % 8, G*S % 8); off-tile shapes fall back to the jnp
-    reference, which the kernel sweep tests pin the kernel against."""
+    The pallas path requires head_dim % 128 and page % 8 and raises
+    otherwise; any chunk length tiles (the kernel pads its query
+    tiles)."""
     from repro.kernels import ref as R
     b, s, h, d = q.shape
-    hk = k_pages.shape[2]
+    n, hk = k_pages.shape[:2]
     g = h // hk
     qg = jnp.swapaxes(q, 1, 2).reshape(b, hk, g, s, d)
-    n = k_pages.shape[0]
     bt = jnp.clip(block_tables, 0, n - 1)
     path = kernel_path("paged_prefill_attention")
-    if path == "ref" or k_scales is not None \
-            or (path == "pallas" and not (d % 128 == 0
-                                          and k_pages.shape[1] % 8 == 0
-                                          and (g * s) % 8 == 0)):
+    if k_scales is not None:
+        path = "ref"
+    elif path == "pallas":
+        _check_paged_tile("paged_prefill_attention", q, k_pages)
+    if _took("paged_prefill_attention", path) == "ref":
         out = R.paged_prefill_attention_ref(qg, k_pages, v_pages, bt,
                                             offset, softcap=softcap,
                                             k_scales=k_scales,
@@ -241,16 +292,16 @@ def dispatch_paged_verify_attention(q, k_pages, v_pages, block_tables,
     (B, S, H*D).
 
     Verify windows are short (K+1 tokens) and off the steady-state
-    decode hot loop, so every path runs the jnp reference for now; a
-    Pallas lowering can slot in behind this front door without touching
-    callers."""
+    decode hot loop, so every path runs the jnp reference for now
+    (recorded as ``ref``); a Pallas lowering can slot in behind this
+    front door without touching callers."""
     from repro.kernels import ref as R
     b, s, h, d = q.shape
-    hk = k_pages.shape[2]
+    n, hk = k_pages.shape[:2]
     g = h // hk
     qg = jnp.swapaxes(q, 1, 2).reshape(b, hk, g, s, d)
-    n = k_pages.shape[0]
     bt = jnp.clip(block_tables, 0, n - 1)
+    _took("paged_verify_attention", "ref")
     out = R.paged_verify_attention_ref(qg, k_pages, v_pages, bt, offset,
                                        softcap=softcap, k_scales=k_scales,
                                        v_scales=v_scales)
@@ -264,7 +315,7 @@ def dispatch_paged_verify_attention(q, k_pages, v_pages, block_tables,
 
 def dispatch_matmul(x, w, bias=None, *, activation="none", out_dtype=None):
     from repro.kernels import ref as R
-    path = kernel_path("matmul")
+    path = _took("matmul", kernel_path("matmul"))
     if path == "ref":
         return R.matmul_fused_ref(x, w, bias, activation=activation,
                                   out_dtype=out_dtype)
@@ -280,7 +331,7 @@ def dispatch_matmul(x, w, bias=None, *, activation="none", out_dtype=None):
 
 def dispatch_layernorm(x, scale, bias=None, *, kind="rmsnorm", eps=1e-6):
     from repro.kernels import ref as R
-    path = kernel_path("layernorm")
+    path = _took("layernorm", kernel_path("layernorm"))
     if path == "ref":
         return R.norm_onepass_ref(x, scale, bias, kind=kind, eps=eps)
     from repro.kernels.layernorm import norm_onepass
@@ -295,13 +346,16 @@ def dispatch_layernorm(x, scale, bias=None, *, kind="rmsnorm", eps=1e-6):
 def use_scan_kernel() -> bool:
     """Whether recurrent models should flatten into the Pallas linear-scan
     kernel (vs the model-side chunked associative scan on the ref path)."""
-    return kernel_path("linear_scan") != "ref"
+    if kernel_path("linear_scan") == "ref":
+        _took("linear_scan", "ref")
+        return False
+    return True
 
 
 def dispatch_linear_scan(a, b, h0=None):
     """a, b: (N, S, F).  Returns all states (N, S, F)."""
     from repro.kernels import ref as R
-    path = kernel_path("linear_scan")
+    path = _took("linear_scan", kernel_path("linear_scan"))
     if path == "ref":
         return R.linear_scan_ref(a, b, h0)
     from repro.kernels.linear_scan import linear_scan
@@ -309,7 +363,7 @@ def dispatch_linear_scan(a, b, h0=None):
 
 
 __all__ = [
-    "kernel_path", "use_flash", "use_scan_kernel",
+    "kernel_path", "record_paths", "use_flash", "use_scan_kernel",
     "dispatch_flash_attention", "dispatch_paged_attention",
     "dispatch_fused_paged_decode",
     "dispatch_paged_prefill_attention", "dispatch_paged_verify_attention",

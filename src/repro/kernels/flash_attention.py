@@ -120,7 +120,7 @@ def flash_attention_bhsd(q, k, v, q_pos, k_pos, k_valid, *, causal=True,
                          lambda b_, h_, i, j, g=groups: (b_, h_ // g, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        out_shape=compat.kernel_out((b, h, sq, d), q.dtype, q, k, v),
         scratch_shapes=[
             compat.vmem_scratch((bq, d), jnp.float32),
             compat.vmem_scratch((bq, 1), jnp.float32),

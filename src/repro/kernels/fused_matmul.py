@@ -87,7 +87,7 @@ def matmul_fused(x, w, bias=None, *, activation="none", block_m=256,
         grid=(m // bm, n // bn, k_blocks),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_shape=compat.kernel_out((m, n), out_dtype, x, w, bias),
         scratch_shapes=[compat.vmem_scratch((bm, bn), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

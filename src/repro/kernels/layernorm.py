@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.backend import compat
+
 
 def _ln_kernel(x_ref, scale_ref, bias_ref, o_ref, *, kind, eps):
     x = x_ref[...].astype(jnp.float32)               # (br, d)
@@ -49,6 +51,6 @@ def norm_onepass(x, scale, bias=None, *, kind="rmsnorm", eps=1e-6,
             pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
+        out_shape=compat.kernel_out((r, d), x.dtype, x, scale, bias),
         interpret=interpret,
     )(x, scale.reshape(1, d), bias.reshape(1, d))

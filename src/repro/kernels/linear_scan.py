@@ -23,22 +23,18 @@ def _scan_kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, block_s):
 
     @pl.when(sb == 0)
     def _init():
-        h_ref[...] = h0_ref[...].astype(jnp.float32)  # (1, bf)
+        h_ref[...] = h0_ref[0].astype(jnp.float32)   # (1, bf)
 
-    a = a_ref[0].astype(jnp.float32)                 # (bs, bf)
-    b = b_ref[0].astype(jnp.float32)
-    h = h_ref[...]                                   # (1, bf)
+    # one row per step, read from and written to the refs at a dynamic
+    # sublane offset (a value cannot be sliced dynamically in-kernel)
+    def step(t, h):
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :].astype(jnp.float32) * h \
+            + b_ref[0, row, :].astype(jnp.float32)
+        o_ref[0, row, :] = h.astype(o_ref.dtype)
+        return h
 
-    def step(t, carry):
-        h, out = carry
-        h = a[t][None, :] * h + b[t][None, :]
-        out = jax.lax.dynamic_update_slice(out, h, (t, 0))
-        return h, out
-
-    out0 = jnp.zeros((block_s, a.shape[1]), jnp.float32)
-    h, out = jax.lax.fori_loop(0, block_s, step, (h, out0))
-    h_ref[...] = h
-    o_ref[0] = out.astype(o_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, block_s, step, h_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_f",
@@ -61,12 +57,14 @@ def linear_scan(a, b, h0=None, *, block_s=256, block_f=512, interpret=False):
         in_specs=[
             pl.BlockSpec((1, bs, bf), lambda i, fb, sb: (i, sb, fb)),
             pl.BlockSpec((1, bs, bf), lambda i, fb, sb: (i, sb, fb)),
-            pl.BlockSpec((1, bf), lambda i, fb, sb: (i, fb)),
+            # h0 rides as (N, 1, F): a (1, bf) block of it is a whole
+            # second-minor dimension, as the TPU tiling requires
+            pl.BlockSpec((1, 1, bf), lambda i, fb, sb: (i, 0, fb)),
         ],
         out_specs=pl.BlockSpec((1, bs, bf), lambda i, fb, sb: (i, sb, fb)),
-        out_shape=jax.ShapeDtypeStruct((n, s, f), a.dtype),
+        out_shape=compat.kernel_out((n, s, f), a.dtype, a, b, h0),
         scratch_shapes=[compat.vmem_scratch((1, bf), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0.reshape(n, 1, f))

@@ -47,13 +47,17 @@ def dequantize_int8(q, scale):
 
 
 def _gather_pages(k_pages, v_pages, bt, k_scales, v_scales, b, nb, p, hk, d):
-    """Gather (and dequantize, when scales are given) pool pages through
-    clipped block tables into logical-ordered (B, NB*P, Hkv, D) f32."""
-    k = k_pages[bt].reshape(b, nb * p, hk, d).astype(jnp.float32)
-    v = v_pages[bt].reshape(b, nb * p, hk, d).astype(jnp.float32)
+    """Gather (and dequantize, when scales are given) head-major pool
+    pages ``(N, Hkv, P, D)`` through clipped block tables into
+    logical-ordered (B, NB*P, Hkv, D) f32."""
+    def rows(x):          # (B, NB, Hkv, P, ...) -> (B, NB*P, Hkv, ...)
+        x = jnp.swapaxes(x, 2, 3)
+        return x.reshape((b, nb * p, hk) + x.shape[4:])
+    k = rows(k_pages[bt]).astype(jnp.float32)
+    v = rows(v_pages[bt]).astype(jnp.float32)
     if k_scales is not None:
-        k = k * k_scales[bt].reshape(b, nb * p, hk)[..., None]
-        v = v * v_scales[bt].reshape(b, nb * p, hk)[..., None]
+        k = k * rows(k_scales[bt])[..., None]
+        v = v * rows(v_scales[bt])[..., None]
     return k, v
 
 
@@ -89,11 +93,11 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     """Decode attention over a paged KV cache, pure jnp.
 
     q: (B, Hkv, G, D) — one query token per slot, q heads grouped per kv
-    head; k_pages/v_pages: (N, P, Hkv, D) physical block pool;
+    head; k_pages/v_pages: (N, Hkv, P, D) physical block pool;
     block_tables: (B, NB) int32 logical->physical map (entries >= N are
     unmapped: clipped to a garbage page and masked); lengths: (B,) valid
     tokens per slot (the query sits at position lengths-1).
-    k_scales/v_scales: (N, P, Hkv) f32 per-row dequant scales when the
+    k_scales/v_scales: (N, Hkv, P) f32 per-row dequant scales when the
     pool holds int8 blocks (None on fp pools).
     Returns (B, Hkv, G, D).
 
@@ -103,7 +107,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     paged decode is bit-identical to dense decode.
     """
     b, hk, g, d = q.shape
-    n, p, _, _ = k_pages.shape
+    n, _, p, _ = k_pages.shape
     nb = block_tables.shape[1]
     dt = q.dtype
     bt = jnp.clip(block_tables, 0, n - 1)
@@ -128,10 +132,10 @@ def fused_paged_decode_ref(q, k_new, v_new, k_pages, v_pages, block_tables,
 
     q: (B, Hkv, G, D) UN-roped grouped queries; k_new/v_new: (B, Hkv, D)
     the slot's un-roped fresh K/V projection; k_pages/v_pages:
-    (N, P, Hkv, D) pool; block_tables: (B, NB) int32 (in-range — the
+    (N, Hkv, P, D) pool; block_tables: (B, NB) int32 (in-range — the
     manager's sentinel rows point at the sink page); positions: (B,)
     int32 write position per slot (= tokens already cached).
-    k_scales/v_scales: (N, P, Hkv) f32 on int8 pools (None on fp).
+    k_scales/v_scales: (N, Hkv, P) f32 on int8 pools (None on fp).
 
     Composes exactly the unfused model-layer sequence: rope q and k_new
     at ``positions`` (``decode_rope_ref`` — bit-identical to
@@ -141,7 +145,7 @@ def fused_paged_decode_ref(q, k_new, v_new, k_pages, v_pages, block_tables,
     (out (B, Hkv, G, D), k_pages, v_pages, k_scales, v_scales).
     """
     b, hk, g, d = q.shape
-    n, page = k_pages.shape[:2]
+    n, page = k_pages.shape[0], k_pages.shape[2]
     nb = block_tables.shape[1]
     cdt = k_pages.dtype
     pos_bs = positions[:, None]                       # (B, 1)
@@ -155,14 +159,15 @@ def fused_paged_decode_ref(q, k_new, v_new, k_pages, v_pages, block_tables,
     if k_scales is not None:
         kq, ks = quantize_int8_rows(kr)
         vq, vs = quantize_int8_rows(v_new)
-        k_pages = k_pages.at[pages, rows].set(kq, mode="drop")
-        v_pages = v_pages.at[pages, rows].set(vq, mode="drop")
-        k_scales = k_scales.at[pages, rows].set(ks, mode="drop")
-        v_scales = v_scales.at[pages, rows].set(vs, mode="drop")
+        k_pages = k_pages.at[pages, :, rows].set(kq, mode="drop")
+        v_pages = v_pages.at[pages, :, rows].set(vq, mode="drop")
+        k_scales = k_scales.at[pages, :, rows].set(ks, mode="drop")
+        v_scales = v_scales.at[pages, :, rows].set(vs, mode="drop")
     else:
-        k_pages = k_pages.at[pages, rows].set(kr.astype(cdt), mode="drop")
-        v_pages = v_pages.at[pages, rows].set(v_new.astype(cdt),
-                                              mode="drop")
+        k_pages = k_pages.at[pages, :, rows].set(kr.astype(cdt),
+                                                 mode="drop")
+        v_pages = v_pages.at[pages, :, rows].set(v_new.astype(cdt),
+                                                 mode="drop")
     out = paged_attention_ref(qr, k_pages, v_pages, block_tables,
                               positions + 1, softcap=softcap,
                               k_scales=k_scales, v_scales=v_scales)
@@ -176,7 +181,7 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables, offset,
 
     q: (B, Hkv, G, S, D) — S fresh query tokens per slot sitting at
     positions ``offset .. offset+S-1``, q heads grouped per kv head;
-    k_pages/v_pages: (N, P, Hkv, D) physical block pool (the fresh
+    k_pages/v_pages: (N, Hkv, P, D) physical block pool (the fresh
     chunk's K/V are already written in); block_tables: (B, NB) int32
     logical->physical map over *all* mapped blocks — shared prefix and
     fresh suffix alike (entries >= N are unmapped: clipped to a garbage
@@ -192,7 +197,7 @@ def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables, offset,
     cold full prefill.
     """
     b, hk, g, s, d = q.shape
-    n, p, _, _ = k_pages.shape
+    n, _, p, _ = k_pages.shape
     nb = block_tables.shape[1]
     dt = q.dtype
     bt = jnp.clip(block_tables, 0, n - 1)
@@ -223,7 +228,7 @@ def paged_verify_attention_ref(q, k_pages, v_pages, block_tables, offset,
     one batched step, exactly as the decode path's per-slot position
     vector allows.
 
-    q: (B, Hkv, G, S, D); k_pages/v_pages: (N, P, Hkv, D) with the
+    q: (B, Hkv, G, S, D); k_pages/v_pages: (N, Hkv, P, D) with the
     window's K/V already written in; block_tables: (B, NB);
     offset: (B,) int32.  Returns (B, Hkv, G, S, D).
 
@@ -234,7 +239,7 @@ def paged_verify_attention_ref(q, k_pages, v_pages, block_tables, offset,
     greedy stream.
     """
     b, hk, g, s, d = q.shape
-    n, p, _, _ = k_pages.shape
+    n, _, p, _ = k_pages.shape
     nb = block_tables.shape[1]
     dt = q.dtype
     bt = jnp.clip(block_tables, 0, n - 1)

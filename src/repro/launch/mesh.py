@@ -12,7 +12,7 @@ never touches jax device state — required because the dry-run must set
 XLA_FLAGS before first jax init while tests/benches see 1 device.
 
 All mesh construction and ambient-mesh context handling routes through
-``repro.backend.compat`` — the jax 0.4.x/0.7.x API split lives there.
+``repro.backend.compat`` (jax 0.9.0 mesh APIs).
 """
 from __future__ import annotations
 

@@ -62,6 +62,7 @@ import time
 import jax
 import numpy as np
 
+from repro.backend import compat
 from repro.configs import REGISTRY, ShapeConfig, reduced
 from repro.models import build_model
 from repro.serving import Request, ServingEngine
@@ -130,6 +131,10 @@ def _adaptive_ladder(cfg, splan, slots: int, chunk: int):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the smoke-size config (configs.reduced); "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=16)
@@ -214,7 +219,10 @@ def main(argv=None):
                          "have no shareable blocks)")
     prefix_cache = True if args.prefix_cache is None else args.prefix_cache
 
-    cfg = reduced(REGISTRY[args.arch])
+    compat.use_compile_cache()
+    cfg = REGISTRY[args.arch]
+    if args.reduced:
+        cfg = reduced(cfg)
     splan = _build_serving_plan(cfg, args.strategy, args.slots,
                                 args.replicas, args.chunk, args.max_seq)
     if splan is not None:
@@ -285,10 +293,11 @@ def main(argv=None):
                   f" migrations={st['migrations']}"
                   f" (copies={st['migration_copies']})"
                   f" final={st['plan_label']}")
+    kernels = ",".join(f"{k}={v}" for k, v in st["kernels"].items())
     print(f"[serve] {len(done)} requests, {st['gen_tokens']} tokens, "
           f"{st['gen_tokens']/wall:.1f} tok/s, "
           f"occupancy={st['slot_occupancy']:.2f}, "
-          f"kernels={st['kernel_path']}{extra}")
+          f"kernels={kernels or st['kernel_path']}{extra}")
     if args.trace:
         eng.write_trace(args.trace)
         print(f"[serve] trace: {args.trace} ({eng._tr.events} events"

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend import compat
 from repro.checkpoint import CheckpointManager, latest_step
 from repro.configs import REGISTRY, ShapeConfig, reduced
 from repro.data import SyntheticLM
@@ -35,12 +36,16 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--grad-accum", type=int, default=1)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the smoke-size config (configs.reduced); "
+                         "--no-reduced trains the published widths")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--straggler-warn-ms", type=float, default=0.0,
                     help="warn when a step exceeds median by this margin")
     args = ap.parse_args(argv)
+    compat.use_compile_cache()
 
     cfg = REGISTRY[args.arch]
     if args.reduced:

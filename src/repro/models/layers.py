@@ -228,32 +228,27 @@ def ring_k_positions(last, W: int):
 
 
 def _paged_write(kv_cache, pages, rows, k, v):
-    """Scatter fresh K/V rows into pool pages, quantizing on int8 pools.
+    """Scatter fresh K/V rows into head-major pool pages ``(N, Hkv, P,
+    D)``, quantizing on int8 pools.
 
     ``pages``/``rows`` index physical (page, row) per fresh token —
     shapes (B,), (B, S), or (S,) matching ``k``/``v``'s leading dims; the
     trailing dims are (Hkv, D).  Out-of-range pages drop the write.
     Returns the new pool-leaf dict (scale leaves updated alongside the
     int8 rows so dequant always sees matching data)."""
-    cdt = kv_cache["k_pages"].dtype
+    def put(pool, x):
+        return pool.at[pages, :, rows].set(x.astype(pool.dtype),
+                                           mode="drop")
     if "k_scales" in kv_cache:
         from repro.kernels import ref as R
         kq, ks = R.quantize_int8_rows(k)
         vq, vs = R.quantize_int8_rows(v)
-        return {
-            "k_pages": kv_cache["k_pages"].at[pages, rows].set(
-                kq, mode="drop"),
-            "v_pages": kv_cache["v_pages"].at[pages, rows].set(
-                vq, mode="drop"),
-            "k_scales": kv_cache["k_scales"].at[pages, rows].set(
-                ks, mode="drop"),
-            "v_scales": kv_cache["v_scales"].at[pages, rows].set(
-                vs, mode="drop"),
-        }
-    return {"k_pages": kv_cache["k_pages"].at[pages, rows].set(
-                k.astype(cdt), mode="drop"),
-            "v_pages": kv_cache["v_pages"].at[pages, rows].set(
-                v.astype(cdt), mode="drop")}
+        return {"k_pages": put(kv_cache["k_pages"], kq),
+                "v_pages": put(kv_cache["v_pages"], vq),
+                "k_scales": put(kv_cache["k_scales"], ks),
+                "v_scales": put(kv_cache["v_scales"], vs)}
+    return {"k_pages": put(kv_cache["k_pages"], k),
+            "v_pages": put(kv_cache["v_pages"], v)}
 
 
 def _scale_kw(cache):
@@ -298,7 +293,7 @@ def multi_head_attention(p, x, cfg: ModelConfig, *, positions=None,
 
     kv_cache: {"k": (B, W, Hkv, D), "v": ...} where W is max_seq for global
     attention or the window size (ring buffer) for local attention — OR a
-    *paged* cache {"k_pages": (N, P, Hkv, D), "v_pages": ...}: a physical
+    *paged* cache {"k_pages": (N, Hkv, P, D), "v_pages": ...}: a physical
     block pool shared by all slots, addressed through ``block_tables``
     ((B, max_blocks) int32, unmapped entries out of range).  Paged caches
     serve two modes: per-slot decode (one token per slot at its own
@@ -385,7 +380,7 @@ def multi_head_attention(p, x, cfg: ModelConfig, *, positions=None,
             raise NotImplementedError(
                 "paged KV caches are addressed through block_tables "
                 "(per-slot decode or batch-1 suffix/chunk prefill)")
-        page = kv_cache["k_pages"].shape[1]
+        page = kv_cache["k_pages"].shape[2]
         from repro.backend import dispatch as kops
         if s == 1 and per_slot and fuse_decode:
             # ---- fused paged decode: RoPE (q and fresh k), the page

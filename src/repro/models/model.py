@@ -15,6 +15,7 @@ Input contracts per family (see ``input_specs``):
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -32,12 +33,25 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+@partial(jax.jit, static_argnums=0)
+def _init_params(cfg: ModelConfig, rng):
+    return Model(cfg)._init(rng)
+
+
 @dataclass(frozen=True)
 class Model:
     cfg: ModelConfig
 
     # ------------------------------------------------------------------ init
     def init(self, rng) -> Dict[str, Any]:
+        """Random parameters from ``rng``, built by ONE jitted program on
+        the default device.  Run eagerly, each stacked leaf would exist
+        as float32 temporaries (draw, then scale) before its cast to
+        ``param_dtype`` — at published widths several times the bf16
+        weights; fused, peak memory stays near the weights themselves."""
+        return _init_params(self.cfg, rng)
+
+    def _init(self, rng) -> Dict[str, Any]:
         cfg = self.cfg
         ks = L.split_keys(rng, 6)
         params: Dict[str, Any] = {}

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.backend import compat
 from repro.configs.base import BlockSpec, ModelConfig
 from repro.models import layers as L
 from repro.models import ssm as S
@@ -29,15 +30,18 @@ BATCH_AXES = ("pod", "data")
 
 
 def constrain(x, spec_axes):
-    """Apply a sharding constraint using only axes present in the ambient
-    abstract mesh.  spec_axes: tuple of axis-name-or-None per dim."""
+    """Apply a sharding constraint using only the Auto axes of the ambient
+    abstract mesh (inside ``shard_map`` its manual axes are not
+    constrainable).  spec_axes: tuple of axis-name-or-None per dim."""
     try:
         mesh = jax.sharding.get_abstract_mesh()
     except Exception:
         return x
     if mesh is None or not mesh.axis_names:
         return x
-    names = set(mesh.axis_names)
+    names = set(mesh.auto_axes)
+    if not names:
+        return x
 
     def _filt(a):
         if a is None:
@@ -260,7 +264,9 @@ def make_paged_cache(cfg: ModelConfig, batch: int, max_seq: int, *,
     pool instead of a dense per-slot reservation.
 
     Global-attn leaves become page pools shaped
-    ``(num_groups, num_blocks + 1, page_size, kv_heads, head_dim)``
+    ``(num_groups, num_blocks + 1, kv_heads, page_size, head_dim)``
+    (head-major: each page of each kv head is one contiguous
+    ``(page_size, head_dim)`` tile, the block the Pallas kernels DMA)
     shared by all ``batch`` slots and addressed through per-slot block
     tables (``repro.cache.PagedCacheManager``); every other leaf —
     local-window rings, mamba/mlstm/slstm state — keeps the dense
@@ -291,8 +297,8 @@ def make_paged_cache(cfg: ModelConfig, batch: int, max_seq: int, *,
     cache = {}
     for j, blk in enumerate(cfg.block_pattern):
         if _is_global_attn(blk.mixer):
-            shp = (cfg.num_groups, num_blocks + 1, page_size,
-                   cfg.num_kv_heads, cfg.head_dim)
+            shp = (cfg.num_groups, num_blocks + 1, cfg.num_kv_heads,
+                   page_size, cfg.head_dim)
             if kv_dtype == "int8":
                 cache[f"b{j}"] = {"kv": {
                     "k_pages": factory(shp, jnp.int8),
@@ -601,7 +607,10 @@ def run_stack(stack_params, x, cfg: ModelConfig, *, positions=None,
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable)
 
-    aux0 = jnp.zeros((), jnp.float32)
+    # under shard_map the body's aux varies over the manual axes that x
+    # and the params vary over; the carry must start out that way too
+    aux0 = compat.pcast_varying(jnp.zeros((), jnp.float32),
+                                compat.varying_axes(x, stack_params))
     (x, aux), new_cache = lax.scan(body, (x, aux0),
                                    (stack_params, cache, group_mask))
     return x, new_cache, aux

@@ -30,6 +30,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -53,8 +54,15 @@ def plan_stage_params(stack_params, plan: ExecutionPlan):
     """(num_groups, ...) stacked params -> (S, max_groups, ...) per the
     plan's stage slices: a clamped gather pads short stages by repeating
     their last real group (masked out at runtime)."""
-    idx = jnp.asarray(plan.group_index_matrix())
-    return jax.tree.map(lambda x: jnp.asarray(x)[idx], stack_params)
+    idx = plan.group_index_matrix()
+    if np.array_equal(idx.ravel(), np.arange(plan.num_groups)):
+        # equal contiguous stages: a reshape, which keeps a stage sharding
+        # of the group axis where it is (a gather would copy the stack)
+        return jax.tree.map(
+            lambda x: jnp.asarray(x).reshape(idx.shape + x.shape[1:]),
+            stack_params)
+    return jax.tree.map(lambda x: jnp.asarray(x)[jnp.asarray(idx)],
+                        stack_params)
 
 
 def run_stage(cfg: ModelConfig, stage_params, x, *, cache=None,
@@ -112,6 +120,16 @@ def make_plan_runner(cfg: ModelConfig, mesh: Mesh, plan: ExecutionPlan
         y, _, _ = T.run_stack(p_local, x, cfg, group_mask=m_local)
         return y
 
+    if S == 1:
+        # one stage: nothing to forward between stages, so no manual
+        # axis — GSPMD shards the stage over the whole mesh.  (A
+        # collective over a size-1 manual axis is refused by XLA's SPMD
+        # partitioner when the other axes stay auto.)
+        def sequential(p_staged, mask, x_all):
+            p0 = jax.tree.map(lambda a: a[0], p_staged)
+            return lax.map(lambda x: stage_apply(p0, mask[0], x), x_all)
+        return sequential
+
     def inner(p_local, m_local, x_all):
         # p_local leaves: (1, max_groups, ...) — this stage's padded groups.
         p_local = jax.tree.map(lambda a: a[0], p_local)
@@ -146,15 +164,16 @@ def make_plan_runner(cfg: ModelConfig, mesh: Mesh, plan: ExecutionPlan
         return outputs
 
     # Only the manual 'stage' axis appears in specs; data/model sharding of
-    # activations is handled by GSPMD (auto axes) outside the shard_map
-    # where the installed jax supports partial-manual (compat falls back to
-    # full-manual with replicated data/model on 0.4.x).
+    # activations is handled by GSPMD (auto axes) inside each stage.  Axes
+    # of size 1 are manual too: GSPMD has nothing to split over them, and
+    # a Pallas (Mosaic) kernel compiles only where no axis is auto.
     batch_in = P(None, None, None, None)
+    manual = {"stage"} | {a for a in mesh.axis_names if mesh.shape[a] == 1}
     pipelined = compat.shard_map(
         inner, mesh=mesh,
         in_specs=(P("stage"), P("stage"), batch_in),
         out_specs=batch_in,
-        manual_axes=frozenset({"stage"}),
+        manual_axes=frozenset(manual),
     )
     return pipelined
 

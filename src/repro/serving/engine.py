@@ -63,6 +63,15 @@ from repro.backend import compat, dispatch
 from repro.models.model import Model
 
 
+def _upload(host):
+    """Device copy of a host array the engine mutates later.  The
+    transfer behind ``jnp.asarray`` may run after the call returns and
+    reads the source buffer until it is done, so an overlapped step that
+    is dispatched before the host bumps ``_pos``/``_cur`` must be handed
+    a snapshot, not the live array."""
+    return jnp.asarray(np.array(host, copy=True))
+
+
 def make_serve_step(model: Model):
     """serve_step(params, cache, tokens, cache_index) ->
     (next_tokens, logits, new_cache) — one greedy decode step.
@@ -263,8 +272,11 @@ class ServingEngine:
         self.cfg = self.model.cfg
         # the engine's prefill/decode steps execute their hot kernels via
         # the dispatch front door (repro.backend.dispatch) inside the model;
-        # record the resolved path so serving stats name what actually ran.
+        # record the resolved default, and collect the path every kernel
+        # actually took while the engine's steps trace, so serving stats
+        # name what ran.
         self.kernel_path = dispatch.kernel_path()
+        self._kernel_paths: Dict[str, set] = {}
         # every step that consumes the engine's cache donates it: the
         # engine always rebinds ``self._cache``/``self._caches[r]`` to the
         # step's output, so the input buffers are dead on return and the
@@ -530,6 +542,10 @@ class ServingEngine:
         step N-1 and runs the next tick's admission bookkeeping.  The
         drained tokens retire slots exactly as sync mode does, one tick
         later; the per-request token streams are identical."""
+        with dispatch.record_paths(self._kernel_paths):
+            return self._tick()
+
+    def _tick(self) -> bool:
         t_enter = time.perf_counter()
         if self._t_tick_end is not None:
             # host wall between ticks: queue-empty waits / caller think
@@ -1012,6 +1028,10 @@ class ServingEngine:
         cap = max(self.decode_steps * self.slots, 1)
         out = {
             "kernel_path": self.kernel_path,
+            # path each dispatched kernel took in this engine's compiled
+            # steps ("pallas" / "interpret" / "ref"; "+"-joined if mixed)
+            "kernels": {k: "+".join(sorted(v))
+                        for k, v in sorted(self._kernel_paths.items())},
             "requests": len(reqs),
             "gen_tokens": gen,
             "decode_steps": self.decode_steps,
@@ -1444,12 +1464,16 @@ class ServingEngine:
         op is serialized behind this step by the cache's data dependency
         — any re-used page is re-written by its new owner's prefill or
         masked until its new owner's own frontier reaches it.  The
-        record entry is skipped as stale at drain."""
+        record entry is skipped as stale at drain.
+
+        Host arrays go up as private copies (``_upload``): a host-to-
+        device transfer may still be reading its source when this method
+        returns, and ``_pos``/``_cur`` are mutated right after dispatch."""
         act = self.active
         racts = self._note_decode_util()
         tr = self._tr
         if self._cur_dev is None:
-            self._cur_dev = jnp.asarray(self._cur)
+            self._cur_dev = _upload(self._cur)
         arrs = []
         rng = []
         if self.plan is None:
@@ -1460,7 +1484,7 @@ class ServingEngine:
                 bt = jnp.asarray(self._pager.table_matrix())
             nxt, _, self._cache = self.serve_step(
                 self.params, self._cache, self._cur_dev,
-                jnp.asarray(self._pos), None, bt)
+                _upload(self._pos), None, bt)
             self._cur_dev = nxt
             if tr is not None:
                 tr.span(("replica", 0), "decode_dispatch", td, args={
@@ -1480,7 +1504,7 @@ class ServingEngine:
                     bt = jnp.asarray(self._pager.table_matrix()[a:b])
                 nxt, self._caches[r] = self._rt.decode_step(
                     self.params, self._caches[r], self._cur_dev[a:b],
-                    jnp.asarray(self._pos[a:b]), bt)
+                    _upload(self._pos[a:b]), bt)
                 self._share_pool(r)
                 self._cur_dev = self._cur_dev.at[a:b].set(nxt)
                 if tr is not None:

@@ -518,7 +518,9 @@ def test_make_paged_cache_layer_layout_mixed_family():
             assert set(sub["kv"]) == {"k_pages", "v_pages"}
             # num_blocks + 1: the pool carries a trailing sink page the
             # fused decode kernel parks unmapped-slot writes in
-            assert sub["kv"]["k_pages"].shape[1:3] == (13, 4)
+            # (blocks + sink, kv heads, page): head-major pages
+            assert sub["kv"]["k_pages"].shape[1:4] == (
+                13, cfg.num_kv_heads, 4)
         else:
             assert "ssm_state" in sub
             leaf = jax.tree.leaves(sub["ssm_state"])[0]
@@ -606,4 +608,5 @@ def test_slice_cache_groups_works_on_paged_leaves():
     cache = model.init_paged_cache(2, 16, page_size=4, num_blocks=8)
     sl = T.slice_cache_groups(cache, 1, 2)
     assert sl["b0"]["kv"]["k_pages"].shape[0] == 2
-    assert sl["b0"]["kv"]["k_pages"].shape[1:3] == (9, 4)   # +1 sink page
+    assert sl["b0"]["kv"]["k_pages"].shape[1:4] == (   # +1 sink page
+        9, cfg.num_kv_heads, 4)

@@ -64,12 +64,25 @@ def test_shard_map_single_device_roundtrip():
 
 
 def test_pcast_varying_is_safe_identity_semantics():
-    # Only the no-op branch is exercisable outside shard_map; on jax with a
-    # real pcast/pvary the executor tests cover the varying-cast in context.
+    # outside shard_map nothing varies: no axes, and the cast is identity
     x = jnp.ones((2, 2))
-    if not hasattr(jax.lax, "pcast") and not hasattr(jax.lax, "pvary"):
-        np.testing.assert_allclose(np.asarray(compat.pcast_varying(x, ("s",))),
-                                   np.asarray(x))
+    assert compat.varying_axes(x, {"w": x}) == ()
+    np.testing.assert_allclose(np.asarray(compat.pcast_varying(x, ())),
+                               np.asarray(x))
+    # inside, a constant cast to the manual axis carries it like the input
+    from jax.sharding import PartitionSpec as P
+    mesh = compat.make_mesh((len(jax.devices()),), ("s",))
+
+    def body(a):
+        assert compat.varying_axes(a) == ("s",)
+        z = compat.pcast_varying(jnp.zeros((), jnp.float32),
+                                 compat.varying_axes(a))
+        assert compat.varying_axes(z) == ("s",)
+        return a + z
+
+    f = compat.shard_map(body, mesh=mesh, in_specs=P("s"), out_specs=P("s"))
+    y = f(jnp.ones((len(jax.devices()) * 2,)))
+    np.testing.assert_allclose(np.asarray(y), 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -174,8 +187,8 @@ def test_dispatch_paged_attention_ref_parity(path, force_path):
     b, h, hk, d = 3, 4, 2, 128
     n, page, nb = 8, 16, 4
     q = jnp.asarray(r.standard_normal((b, 1, h, d)), jnp.float32)
-    kp = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
-    vp = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
+    kp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
+    vp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
     bt = jnp.asarray([[3, 1, n, n], [5, 2, 7, n], [0, n, n, n]], jnp.int32)
     lens = jnp.asarray([20, 37, 3], jnp.int32)
     out = dispatch.dispatch_paged_attention(q, kp, vp, bt, lens)
@@ -200,8 +213,8 @@ def test_dispatch_paged_prefill_attention_ref_parity(path, offset,
     n, page = 8, 8
     s = 8
     q = jnp.asarray(r.standard_normal((b, s, h, d)), jnp.float32)
-    kp = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
-    vp = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
+    kp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
+    vp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
     bt = jnp.asarray([[3, 1, 6, n]], jnp.int32)  # covers offset+s, 1 pad
     out = dispatch.dispatch_paged_prefill_attention(q, kp, vp, bt, offset)
     qg = jnp.swapaxes(q, 1, 2).reshape(b, hk, h // hk, s, d)
@@ -300,8 +313,8 @@ def test_dispatch_fused_paged_decode_ref_parity(path, force_path):
     q = jnp.asarray(r.standard_normal((b, 1, h, d)), jnp.float32)
     kn = jnp.asarray(r.standard_normal((b, 1, hk, d)), jnp.float32)
     vn = jnp.asarray(r.standard_normal((b, 1, hk, d)), jnp.float32)
-    kp = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
-    vp = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
+    kp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
+    vp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
     bt = jnp.asarray(r.permutation(b * nb).reshape(b, nb), jnp.int32)
     pos = jnp.asarray([page - 1, page + 7], jnp.int32)
     out, nkp, nvp, ks, vs = dispatch.dispatch_fused_paged_decode(
@@ -327,8 +340,8 @@ def test_dispatch_paged_attention_int8_dequant_parity(force_path):
     b, h, hk, d = 2, 4, 2, 128
     n, page, nb = 5, 16, 2
     q = jnp.asarray(r.standard_normal((b, 1, h, d)), jnp.float32)
-    kf = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
-    vf = jnp.asarray(r.standard_normal((n, page, hk, d)), jnp.float32)
+    kf = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
+    vf = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
     kq, ks = R.quantize_int8_rows(kf)
     vq, vs = R.quantize_int8_rows(vf)
     bt = jnp.asarray([[3, 1], [0, 2]], jnp.int32)
@@ -339,3 +352,77 @@ def test_dispatch_paged_attention_int8_dequant_parity(force_path):
         q, R.dequantize_int8(kq, ks), R.dequantize_int8(vq, vs), bt, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(deq),
                                atol=2e-6, rtol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# pallas path: no silent fallback; every path taken is recorded
+# ---------------------------------------------------------------------------
+
+def _paged_operands(d, page, hk=2, h=4, b=2, nb=2):
+    r = np.random.default_rng(8)
+    n = b * nb + 1
+    q = jnp.asarray(r.standard_normal((b, 1, h, d)), jnp.float32)
+    kp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
+    bt = jnp.asarray(np.arange(b * nb).reshape(b, nb), jnp.int32)
+    return q, kp, bt
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "fused_paged_decode",
+                                    "paged_prefill_attention"])
+def test_pallas_path_off_tile_raises_naming_kernel(kernel, force_path):
+    """head_dim 64 is off the TPU lane tile: the pallas path must refuse
+    with the kernel's name and the shape, not run the jnp reference."""
+    force_path("pallas")
+    q, kp, bt = _paged_operands(d=64, page=16)
+    lens = jnp.asarray([5, 9], jnp.int32)
+    off_tile = kernel + r".*\(2, 1, 4, 64\).*\(5, 2, 16, 64\)"
+    with pytest.raises(ValueError, match=off_tile):
+        if kernel == "paged_attention":
+            dispatch.dispatch_paged_attention(q, kp, kp, bt, lens)
+        elif kernel == "fused_paged_decode":
+            kn = q[:, :, :2]
+            dispatch.dispatch_fused_paged_decode(q, kn, kn, kp, kp, bt,
+                                                 lens, theta=1e4)
+        else:
+            dispatch.dispatch_paged_prefill_attention(q, kp, kp, bt[:1],
+                                                      jnp.int32(3))
+
+
+def test_record_paths_reports_reference_runs(force_path):
+    """int8 pools and paged verify run the jnp reference by design, on
+    every path; the recorder names them ``ref`` next to the kernels
+    that really ran."""
+    force_path("interpret")
+    q, kp, bt = _paged_operands(d=128, page=16)
+    kq, ks = R.quantize_int8_rows(kp)
+    lens = jnp.asarray([5, 20], jnp.int32)
+    seen = {}
+    with dispatch.record_paths(seen):
+        dispatch.dispatch_paged_attention(q, kp, kp, bt, lens)
+        dispatch.dispatch_paged_prefill_attention(
+            q[:1], kq, kq, bt[:1], jnp.int32(0), k_scales=ks, v_scales=ks)
+        dispatch.dispatch_paged_verify_attention(q, kp, kp, bt, lens)
+    assert seen == {"paged_attention": {"interpret"},
+                    "paged_prefill_attention": {"ref"},
+                    "paged_verify_attention": {"ref"}}
+    # outside the block nothing is recorded
+    dispatch.dispatch_paged_attention(q, kp, kp, bt, lens)
+    assert seen["paged_attention"] == {"interpret"}
+
+
+def test_use_compile_cache_honours_env_else_fixed_path(monkeypatch):
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compat.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None   # not ours
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compat.use_compile_cache()
+        assert path == os.path.join(os.path.abspath(REPO), ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compat.use_compile_cache() == path           # fixed, stable
+        with open(os.path.join(REPO, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -167,13 +167,13 @@ def _fused_setup(seed, *, b=3, h=4, hk=2, d=128, page=32, nb=3,
     bt = jnp.asarray(r.permutation(b * nb).reshape(b, nb), jnp.int32)
     pos = jnp.asarray([page - 1, page + 5, 2 * page + 17][:b], jnp.int32)
     if int8:
-        kf = r.standard_normal((n, page, hk, d))
-        vf = r.standard_normal((n, page, hk, d))
+        kf = r.standard_normal((n, hk, page, d))
+        vf = r.standard_normal((n, hk, page, d))
         kp, ks = R.quantize_int8_rows(jnp.asarray(kf, jnp.float32))
         vp, vs = R.quantize_int8_rows(jnp.asarray(vf, jnp.float32))
         return q, kn, vn, kp, vp, bt, pos, ks, vs
-    kp = jnp.asarray(r.standard_normal((n, page, hk, d)), kv_dtype)
-    vp = jnp.asarray(r.standard_normal((n, page, hk, d)), kv_dtype)
+    kp = jnp.asarray(r.standard_normal((n, hk, page, d)), kv_dtype)
+    vp = jnp.asarray(r.standard_normal((n, hk, page, d)), kv_dtype)
     return q, kn, vn, kp, vp, bt, pos, None, None
 
 
@@ -194,7 +194,7 @@ def test_fused_paged_decode_ref_composes_unfused_ops_bitwise():
     """fused_paged_decode_ref == rope -> scatter -> paged_attention_ref,
     bit-for-bit (it IS that composition — this pins it)."""
     q, kn, vn, kp, vp, bt, pos, _, _ = _fused_setup(31)
-    theta, page = 10000.0, kp.shape[1]
+    theta, page = 10000.0, kp.shape[2]
     out, nkp, nvp, _, _ = R.fused_paged_decode_ref(
         q, kn, vn, kp, vp, bt, pos, theta=theta)
     b, hk, g, d = q.shape
@@ -204,8 +204,8 @@ def test_fused_paged_decode_ref_composes_unfused_ops_bitwise():
     blk = jnp.clip(pos // page, 0, bt.shape[1] - 1)
     pages = jnp.take_along_axis(bt, blk[:, None], axis=1)[:, 0]
     rows = pos % page
-    ekp = kp.at[pages, rows].set(kr, mode="drop")
-    evp = vp.at[pages, rows].set(vn, mode="drop")
+    ekp = kp.at[pages, :, rows].set(kr, mode="drop")
+    evp = vp.at[pages, :, rows].set(vn, mode="drop")
     eout = R.paged_attention_ref(qr, ekp, evp, bt, pos + 1)
     assert np.array_equal(np.asarray(nkp), np.asarray(ekp))
     assert np.array_equal(np.asarray(nvp), np.asarray(evp))
@@ -280,3 +280,27 @@ def test_int8_quantize_roundtrip_error_bound():
     # zero rows must not divide by zero
     q0, s0 = R.quantize_int8_rows(jnp.zeros((2, 3, 8)))
     assert np.asarray(q0).max() == 0 and np.isfinite(np.asarray(s0)).all()
+
+
+@pytest.mark.parametrize("s,offset", [(300, 0), (40, 37)])
+def test_paged_prefill_kernel_query_tiles_match_ref(s, offset):
+    """Chunks longer than one query tile (300 rows: two tiles, the second
+    padded) and a short warm chunk at an unaligned offset match the
+    reference in interpret mode."""
+    from repro.kernels.paged_attention import (
+        PREFILL_TQ, paged_prefill_attention_grouped)
+    assert s <= PREFILL_TQ or s % PREFILL_TQ
+    r = _rng(41)
+    hk, g, d, page = 2, 2, 128, 16
+    nb = -(-(offset + s) // page) + 1
+    n = nb + 3
+    q = jnp.asarray(r.standard_normal((1, hk, g, s, d)), jnp.float32)
+    kp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
+    vp = jnp.asarray(r.standard_normal((n, hk, page, d)), jnp.float32)
+    bt = jnp.asarray(r.permutation(n)[:nb][None], jnp.int32)
+    out = paged_prefill_attention_grouped(q, kp, vp, bt, offset,
+                                          interpret=True)
+    ref = R.paged_prefill_attention_ref(q, kp, vp, bt, offset)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+

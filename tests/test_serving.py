@@ -75,6 +75,22 @@ def test_engine_stats_ordering_and_occupancy():
     assert all(t >= 0 for t in st["ttft_s"])
 
 
+def test_engine_stats_name_each_kernels_path(monkeypatch):
+    """stats()["kernels"] names the path each dispatched kernel took in
+    the engine's compiled steps — per kernel, not the global setting."""
+    monkeypatch.setenv("REPRO_KERNELS", "ref,fused_paged_decode=interpret")
+    cfg, model, params = setup()
+    eng = ServingEngine(model, params, slots=2, max_seq=32, paged=True,
+                        page_size=4)
+    for uid in range(2):
+        eng.submit(Request(uid, np.arange(1, 6 + uid, dtype=np.int32), 3))
+    eng.run()
+    kernels = eng.stats()["kernels"]
+    assert kernels["fused_paged_decode"] == "interpret"
+    assert kernels["paged_prefill_attention"] == "ref"
+    assert eng.stats()["kernel_path"] == "ref"
+
+
 def test_stats_report_cache_memory_utilization():
     """stats()["cache"] reports live vs reserved tokens for the dense
     layout, plus block-pool occupancy and prefix-reuse figures when
