@@ -373,6 +373,7 @@ def fused_paged_decode_grouped(q, k_new, v_new, k_pages, v_pages,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
+        name="fused_paged_decode_grouped",
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -480,6 +481,7 @@ def paged_prefill_attention_grouped(q, k_pages, v_pages, block_tables,
         grid_spec=grid_spec,
         out_shape=compat.kernel_out((b, hk, g, nq * tq, d), q.dtype, q,
                                     k_pages, v_pages, offset),
+        name="paged_prefill_attention_grouped",
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),

@@ -1,15 +1,16 @@
 """Observability for the serving engine: tracing, metrics, exporters.
 
-- ``trace``: ring-buffered monotonic-clock :class:`Tracer` (strictly
-  no-op when disabled) and :class:`TraceConfig`.
+- ``trace``: the engine's always-on :class:`PhaseRecorder` (phase
+  clock, span log, profiler annotations) and the opt-in ring-buffered
+  :class:`Tracer` (strictly no-op when disabled), :class:`TraceConfig`.
 - ``metrics``: :class:`MetricsRegistry` of counters/gauges/histograms
   with Prometheus text exposition.
 - ``derive``: typed :class:`TrafficSnapshot` for the adaptive
-  controller and trace-derived utilization views.
+  controller, and the fold of ``stats()`` onto the registry.
 - ``export``: Chrome/Perfetto trace_event JSON writer + validator,
   Prometheus file writer.
 """
-from .derive import TrafficSnapshot, fold_engine_metrics, utilization_from_trace
+from .derive import TrafficSnapshot, fold_engine_metrics
 from .metrics import (
     TPOT_BUCKETS,
     TTFT_BUCKETS,
@@ -18,7 +19,7 @@ from .metrics import (
     Histogram,
     MetricsRegistry,
 )
-from .trace import TraceConfig, Tracer
+from .trace import PHASE_BUCKETS, PhaseRecorder, TraceConfig, Tracer
 from .export import to_perfetto, validate_perfetto, write_metrics, write_trace
 
 __all__ = [
@@ -26,6 +27,8 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
+    "PHASE_BUCKETS",
+    "PhaseRecorder",
     "TPOT_BUCKETS",
     "TTFT_BUCKETS",
     "TraceConfig",
@@ -33,7 +36,6 @@ __all__ = [
     "TrafficSnapshot",
     "fold_engine_metrics",
     "to_perfetto",
-    "utilization_from_trace",
     "validate_perfetto",
     "write_metrics",
     "write_trace",

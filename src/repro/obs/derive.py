@@ -1,16 +1,14 @@
-"""Derived observability: typed traffic snapshots and utilization views.
+"""Derived observability: typed traffic snapshots and registry folds.
 
 ``TrafficSnapshot`` is the single typed observation the adaptive
 controller reads each window (it used to be an ad-hoc dict built inside
-the controller from engine internals).  ``utilization_from_trace``
-recomputes time-based stage/replica utilization from the trace stream;
-``fold_engine_metrics`` projects an engine ``stats()`` dict onto the
-Prometheus registry as gauges.
+the controller from engine internals).  ``fold_engine_metrics`` projects
+an engine ``stats()`` dict onto the Prometheus registry as gauges.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 from .metrics import MetricsRegistry
 
@@ -40,44 +38,6 @@ class TrafficSnapshot:
     active: int
     violated: bool
     window_s: float
-
-
-def utilization_from_trace(tracer: Any) -> Dict[str, Any]:
-    """Time-based stage/replica utilization from retained trace records.
-
-    Walks span records: ``("stage", j)`` tracks accumulate prefill busy
-    time, ``("replica", r)`` tracks accumulate decode/verify busy time.
-    The window is the full [min t0, max t1] extent of retained records.
-    """
-    stage_busy: Dict[int, float] = {}
-    replica_busy: Dict[int, float] = {}
-    tmin: Optional[float] = None
-    tmax: Optional[float] = None
-    for rec in tracer.records():
-        if rec[0] != "X":
-            continue
-        _, track, _name, t0, t1 = rec[:5]
-        tmin = t0 if tmin is None else min(tmin, t0)
-        tmax = t1 if tmax is None else max(tmax, t1)
-        if isinstance(track, tuple):
-            kind, idx = track
-            if kind == "stage":
-                stage_busy[idx] = stage_busy.get(idx, 0.0) + (t1 - t0)
-            elif kind == "replica":
-                replica_busy[idx] = replica_busy.get(idx, 0.0) + (t1 - t0)
-    window = (tmax - tmin) if (tmin is not None and tmax is not None) else 0.0
-    out: Dict[str, Any] = {
-        "window_s": window,
-        "stage_busy_s": dict(sorted(stage_busy.items())),
-        "replica_busy_s": dict(sorted(replica_busy.items())),
-    }
-    if window > 0:
-        out["stage_busy_frac"] = {s: b / window for s, b in sorted(stage_busy.items())}
-        out["replica_busy_frac"] = {r: b / window for r, b in sorted(replica_busy.items())}
-    else:
-        out["stage_busy_frac"] = {}
-        out["replica_busy_frac"] = {}
-    return out
 
 
 def fold_engine_metrics(reg: MetricsRegistry, st: Dict[str, Any]) -> None:

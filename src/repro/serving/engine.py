@@ -433,25 +433,25 @@ class ServingEngine:
         #                                  stays 0 on the paged path for
         #                                  models with no dense slot
         #                                  leaves (the zero-copy claim)
-        # host wall-clock per engine phase, accumulated across ticks.
-        # "host_sync" is an OVERLAY bucket, not a partition member: it
-        # accrues inside whichever phase window is open and measures how
-        # much of that phase the host spent blocked on device readback
-        # (the quantity the async runtime shrinks) — see _sync().
-        # "replan" charges controller decisions + live swaps, so the
-        # migration interval is accounted, never lost or double-counted.
-        # "idle" is the host wall between one tick's end and the next
-        # tick's start (queue-empty waits, caller think time), so the
-        # partition buckets (all but the host_sync overlay) sum to the
-        # first-tick-start → last-tick-end wall.
-        self.phase_time = {"admission": 0.0, "prefill": 0.0, "decode": 0.0,
-                           "replan": 0.0, "idle": 0.0, "host_sync": 0.0}
-        self._prefill_window = 0.0        # prefill seconds inside _admit()
+        # host wall-clock per engine phase, from the always-on phase
+        # recorder (repro.obs.PhaseRecorder): every host phase of a tick
+        # is one span, whose own time accrues to its bucket (admission,
+        # prefill, decode, replan: the admission loop less the prefills
+        # it runs, the rest one after another), and whose name shows in a
+        # profile as a TraceAnnotation.  "host_sync" is an OVERLAY
+        # bucket, not a partition member: it accrues inside whichever
+        # phase is open and measures how much of that phase the host
+        # spent blocked on device readback (the quantity the async
+        # runtime shrinks) — see _sync().  "replan" charges controller
+        # decisions + live swaps, so the migration interval is accounted,
+        # never lost or double-counted.  "idle" is the host wall between
+        # one tick's end and the next tick's start (queue-empty waits,
+        # caller think time), so the partition buckets (all but the
+        # host_sync overlay) sum to the first-tick-start → last-tick-end
+        # wall, less the tick's few statements between its phases.
+        from repro.obs import PhaseRecorder
+        self._phases = PhaseRecorder()
         self._t_window = time.perf_counter()  # stats window start (reset_stats)
-        self._t_tick_end = None           # end of the previous tick (idle
-        #                                   accrues from here to the next
-        #                                   tick's entry)
-        self._t_first_tick = None         # first tick entry this window
         self.submitted = 0                # lifetime submissions (monotonic)
         self._arrival_log = []            # (t_submit, prompt_len, max_new)
         #                                  ring consumed by the controller
@@ -461,12 +461,14 @@ class ServingEngine:
             self._ctl = ReplanController(self.adapt)
             self._ctl.validate(self)
         # observability ----------------------------------------------------
-        # Always on: a MetricsRegistry (one histogram observe + two counter
-        # incs per retirement) and per-stage / per-replica utilization
-        # accumulators (integer adds per decode dispatch).  Strictly
-        # opt-in: the Tracer — self._tr stays None unless trace= is passed
-        # (or enable_trace() is called) and every emission site is guarded
-        # on it, so the disabled hot path allocates no event records.
+        # Always on: the phase recorder above, a MetricsRegistry (one
+        # histogram observe + two counter incs per retirement) and
+        # per-stage / per-replica utilization accumulators (integer adds
+        # per decode dispatch).  Strictly opt-in: the Tracer — self._tr
+        # stays None unless trace= is passed (or enable_trace() is
+        # called); the phase recorder forwards to it only when attached
+        # and every other emission site is guarded on it, so the disabled
+        # hot path allocates no event records.
         from repro.obs import MetricsRegistry, TPOT_BUCKETS, TTFT_BUCKETS
         self.metrics = MetricsRegistry()
         self._h_ttft = self.metrics.histogram(
@@ -517,6 +519,7 @@ class ServingEngine:
             cfg = TraceConfig()
         self.trace_config = cfg
         self._tr = Tracer(cfg.capacity)
+        self._phases.tracer = self._tr
         if self._pf is not None:
             self._pf.tracer = self._tr
         return self._tr
@@ -525,15 +528,23 @@ class ServingEngine:
     def active(self) -> int:
         return sum(r is not None for r in self._slot_req)
 
+    def _active_in(self, a: int, b: int) -> int:
+        return sum(self._slot_req[s] is not None for s in range(a, b))
+
+    @property
+    def _label(self) -> str:
+        return self.plan.label if self.plan is not None else "mono"
+
     def tick(self) -> bool:
         """Admit whatever fits, advance any in-flight chunked prefills by
         one stage-step, then run one batched decode step per replica.
         Returns True while there is (or may be) work in flight.
 
-        Each phase's host wall-clock accrues in ``phase_time`` (the
-        prefill compute launched inside admission is credited to
-        "prefill", so "admission" is pure bookkeeping — block matching,
-        allocation, padding).
+        Each phase is a span of the phase recorder, and its host
+        wall-clock accrues in ``phase_time_s`` (the prefill compute
+        launched inside admission is credited to "prefill", so
+        "admission" is pure bookkeeping — block matching, allocation,
+        padding, slot choice, activation).
 
         Overlap mode reorders the decode phase: this tick's step is
         DISPATCHED first (its inputs are the previous step's output array,
@@ -542,25 +553,19 @@ class ServingEngine:
         step N-1 and runs the next tick's admission bookkeeping.  The
         drained tokens retire slots exactly as sync mode does, one tick
         later; the per-request token streams are identical."""
-        with dispatch.record_paths(self._kernel_paths):
+        with dispatch.record_paths(self._kernel_paths), self._phases.tick():
             return self._tick()
 
     def _tick(self) -> bool:
-        t_enter = time.perf_counter()
-        if self._t_tick_end is not None:
-            # host wall between ticks: queue-empty waits / caller think
-            # time — the bucket that makes phase_time_s sum to the wall
-            self.phase_time["idle"] += t_enter - self._t_tick_end
-        if self._t_first_tick is None:
-            self._t_first_tick = t_enter
+        ph = self._phases
         tr = self._tr
         if tr is not None:
             tr.counter("tick", "engine", {"queue": len(self.queue),
-                                          "active": self.active}, t=t_enter)
+                                          "active": self.active})
         if self._ctl is not None and not self._ctl.paused:
-            tc = time.perf_counter()
-            decision = self._ctl.observe(self)  # None = keep, (plan,) = swap
-            self.phase_time["replan"] += time.perf_counter() - tc
+            with ph.span("engine.control"):
+                # None = keep, (plan,) = swap
+                decision = self._ctl.observe(self)
             if tr is not None and self._ctl.last_scores is not None:
                 # a decision tick: record what the controller weighed
                 tr.instant("tick", "replan_decision", args={
@@ -570,55 +575,51 @@ class ServingEngine:
                                   if decision[0] is not None else "mono"))})
             if decision is not None:
                 self.replan(decision[0])
-        t0 = time.perf_counter()
-        self._prefill_window = 0.0
-        q0 = len(self.queue)
-        self._admit()
-        t1 = time.perf_counter()
-        self.phase_time["admission"] += (t1 - t0) - self._prefill_window
-        self.phase_time["prefill"] += self._prefill_window
-        if tr is not None and q0:
-            tr.span("tick", "admission", t0, t1, args={
-                "queued": q0, "admitted": q0 - len(self.queue),
-                "plan": self.plan.label if self.plan is not None else "mono"})
+        if self.queue:
+            q0 = len(self.queue)
+            with ph.span("engine.admission", args=lambda: {
+                    "queued": q0, "admitted": q0 - len(self.queue),
+                    "plan": self._label}):
+                self._admit()
         if self._pf is not None and self._pf.busy:
-            # paged stage steps thread the replica caches; after a re-plan
-            # to monolithic the drained items route through a one-entry
-            # list wrapping the monolithic cache (item.replica remapped 0)
-            if self.paged:
-                clist = self._caches if self.plan is not None \
-                    else [self._cache]
-            else:
-                clist = self._caches if self.plan is not None else None
-            finished = self._pf.step(caches=clist,
-                                     on_chunk=self._chunk_committed)
-            self._pipeline_ticks += 1
-            for s in self._pf.last_stages_run:
-                self._stage_busy[s] = self._stage_busy.get(s, 0) + 1
-            if self.paged and self.plan is None:
-                self._cache = clist[0]
-            for item in finished:
-                self._finish_prefill(item)
-            self.phase_time["prefill"] += time.perf_counter() - t1
+            with ph.span("engine.prefill.step"):
+                # paged stage steps thread the replica caches; after a
+                # re-plan to monolithic the drained items route through a
+                # one-entry list wrapping the monolithic cache (item.replica
+                # remapped 0)
+                if self.paged:
+                    clist = self._caches if self.plan is not None \
+                        else [self._cache]
+                else:
+                    clist = self._caches if self.plan is not None else None
+                finished = self._pf.step(caches=clist,
+                                         on_chunk=self._chunk_committed)
+                self._pipeline_ticks += 1
+                for s in self._pf.last_stages_run:
+                    self._stage_busy[s] = self._stage_busy.get(s, 0) + 1
+                if self.paged and self.plan is None:
+                    self._cache = clist[0]
+                for item in finished:
+                    with ph.span("engine.prefill.finish", item.req.uid):
+                        self._finish_prefill(item)
         if self._pf is not None and self.plan is None and not self._pf.busy:
             self._pf = None       # old pipeline fully drained post-re-plan
         if self.active or self._inflight:
-            t2 = time.perf_counter()
-            dispatched = False
-            if self.active:
-                if self._overlap:
-                    self._dispatch_decode()
-                    dispatched = True
-                else:
-                    self._decode_once()
-            # one-step-delayed drain: the newest dispatch stays in flight
-            # while its predecessor's tokens come back; once nothing new
-            # dispatches, drain everything so the last slots retire.
-            while len(self._inflight) > (1 if dispatched else 0):
-                self._drain_one()
-            self.phase_time["decode"] += time.perf_counter() - t2
+            with ph.span("engine.decode"):
+                dispatched = False
+                if self.active:
+                    if self._overlap:
+                        self._dispatch_decode()
+                        dispatched = True
+                    else:
+                        self._decode_once()
+                # one-step-delayed drain: the newest dispatch stays in
+                # flight while its predecessor's tokens come back; once
+                # nothing new dispatches, drain everything so the last
+                # slots retire.
+                while len(self._inflight) > (1 if dispatched else 0):
+                    self._drain_one()
         self.ticks += 1
-        self._t_tick_end = time.perf_counter()
         return bool(self.active or self.queue or self._inflight
                     or (self._pf is not None and self._pf.busy))
 
@@ -652,30 +653,37 @@ class ServingEngine:
             the final ticks run — token streams are unaffected).
 
         Stats windows are CONTINUOUS across the swap: no counter resets,
-        the swap's own wall time accrues in ``phase_time["replan"]``, and
-        the surviving pool re-attaches to the engine-lifetime peak
-        tracker (idempotent), so the concurrent peak spans the swap.
+        the swap's own wall time accrues in ``phase_time_s["replan"]``
+        (an ``engine.replan`` span, ``engine.rebalance`` for work
+        stealing), and the surviving pool re-attaches to the
+        engine-lifetime peak tracker (idempotent), so the concurrent peak
+        spans the swap.
 
         ``replan(current_plan)`` degenerates to pure cross-replica work
         stealing: re-balance the active slots over the replicas with
         zero-copy migrations (``rebalance=False`` suppresses it)."""
-        from repro.models import transformer as T
-        t0 = time.perf_counter()
-        old_label = self.plan.label if self.plan is not None else "mono"
         if plan is not None and plan.slots != self.slots:
             raise ValueError(
                 f"ServingPlan was lowered for {plan.slots} slots "
                 f"but the engine has {self.slots}; re-lower via "
                 f"lower_serving(plan, slots={self.slots})")
+        old = self._label
         if plan == self.plan:
             if rebalance and self.plan is not None:
-                self._drain_inflight()
-                self._rebalance_slots()
-                if self._tr is not None:
-                    self._tr.span("tick", "rebalance", t0, args={
-                        "plan": old_label, "migrations": self.migrations})
-            self.phase_time["replan"] += time.perf_counter() - t0
+                with self._phases.span("engine.rebalance", args=lambda: {
+                        "plan": old, "migrations": self.migrations}):
+                    self._drain_inflight()
+                    self._rebalance_slots()
             return
+        with self._phases.span("engine.replan", args=lambda: {
+                "from": old, "to": self._label,
+                "migrations": self.migrations,
+                "migration_copies": self.migration_copies}):
+            self._swap(plan, rebalance)
+
+    def _swap(self, plan, rebalance: bool):
+        """``replan``'s body for a plan other than the current one."""
+        from repro.models import transformer as T
         # 1. land everything in flight on the old binding
         self._drain_inflight()
         # 2. collect the full slot-axis cache (dense leaves concat in
@@ -720,13 +728,6 @@ class ServingEngine:
         if rebalance and plan is not None:
             self._rebalance_slots()
         self.replans += 1
-        self.phase_time["replan"] += time.perf_counter() - t0
-        if self._tr is not None:
-            self._tr.span("tick", "replan", t0, args={
-                "from": old_label,
-                "to": plan.label if plan is not None else "mono",
-                "migrations": self.migrations,
-                "migration_copies": self.migration_copies})
 
     def warm_replans(self):
         """Exercise every adaptive candidate once (measured profiles,
@@ -758,7 +759,7 @@ class ServingEngine:
         land first.  The same drain the final ticks run — token streams
         are unaffected."""
         while self._inflight:
-            self._drain_one()
+            self._drain_one("engine.replan.drain")
         self._cur_dev = None
 
     def _rebalance_slots(self):
@@ -845,8 +846,8 @@ class ServingEngine:
         self.replans = 0
         self.migrations = 0
         self.migration_copies = 0
-        self.phase_time = {"admission": 0.0, "prefill": 0.0, "decode": 0.0,
-                           "replan": 0.0, "idle": 0.0, "host_sync": 0.0}
+        self._phases.reset()         # buckets, span log; idle accrues only
+        #                              BETWEEN ticks of the new window
         self._stage_busy = {}
         self._pipeline_ticks = 0
         self._replica_busy = {}
@@ -856,8 +857,6 @@ class ServingEngine:
         # stats() wall window clamps to this timestamp so the measured
         # window never reaches back before the reset
         self._t_window = time.perf_counter()
-        self._t_tick_end = None       # idle accrues only BETWEEN ticks of
-        self._t_first_tick = None     # the new window
         self._peak_tracker.reset()
         for pager in self._all_pagers():
             p = pager.pool
@@ -1053,12 +1052,17 @@ class ServingEngine:
             "ttft_s": [r.t_first - r.t_submit for r in reqs],
             "latency_s": [r.t_done - r.t_submit for r in reqs],
             "ticks": self.ticks,
-            "phase_time_s": dict(self.phase_time),
+            "phase_time_s": dict(self._phases.time),
+            # the phase spans of the window, (name, t0_ns, t1_ns, tick,
+            # uid) on the perf_counter_ns clock, still-queued requests
+            # with their wait so far (repro.obs.PhaseRecorder.spans)
+            "spans": self._phases.spans(
+                [(r.uid, r.t_submit) for r in self.queue]),
+            "spans_dropped": self._phases.dropped,
             "cache": self.cache_stats(),
             "utilization": self.utilization_stats(),
         }
-        out["plan_label"] = (self.plan.label if self.plan is not None
-                             else "mono")
+        out["plan_label"] = self._label
         if self.plan is not None:
             out["plan_stages"] = self.plan.n_stages
             out["decode_replicas"] = self.plan.n_replicas
@@ -1075,7 +1079,7 @@ class ServingEngine:
         critical path by dispatching the next step first."""
         t0 = time.perf_counter()
         arr = np.asarray(x)
-        self.phase_time["host_sync"] += time.perf_counter() - t0
+        self._phases.time["host_sync"] += time.perf_counter() - t0
         return arr
 
     def _padded_len(self, n: int) -> int:
@@ -1181,52 +1185,44 @@ class ServingEngine:
         remaining suffix, writing fresh pages through ``write_table`` and
         attending the warm ones through ``block_table``.  Cold prompts
         are the reused=0 special case of the same path."""
-        plen = len(req.prompt)
-        if self._pager is not None:
-            # speculation headroom: a verify window may write K positions
-            # past the plain-decode frontier before rolling back, so the
-            # growth reservation covers them
-            ap = self._pager.admit(slot, req.prompt,
-                                   req.max_new_tokens + self._spec_k,
-                                   reuse_compute=self._suffix_reuse)
-            if ap is None:
-                return False
-            reused = ap.reused_tokens
-            suffix = req.prompt[reused:]
-            slen = len(suffix)
+        with self._phases.span("engine.admit", req.uid) as adm:
+            reused = 0
+            if self._pager is not None:
+                # speculation headroom: a verify window may write K
+                # positions past the plain-decode frontier before rolling
+                # back, so the growth reservation covers them
+                ap = self._pager.admit(slot, req.prompt,
+                                       req.max_new_tokens + self._spec_k,
+                                       reuse_compute=self._suffix_reuse)
+                if ap is None:
+                    adm.name = "engine.admit.blocked"  # not an admission
+                    return False
+                reused = ap.reused_tokens
+            slen = len(req.prompt) - reused
             toks = np.zeros((1, self._padded_len(slen)), np.int32)
-            toks[0, :slen] = suffix
-            t0 = time.perf_counter()
-            nxt, self._cache = self._prefill_suffix_paged(
-                self.params, self._cache, jnp.asarray(toks),
-                jnp.int32(slot), jnp.int32(reused), jnp.int32(slen),
-                jnp.asarray(ap.block_table)[None],
-                jnp.asarray(ap.write_table)[None])
-            self._pager.commit(slot)      # pages landed: publish for reuse
+            toks[0, :slen] = req.prompt[reused:]
+        self._phases.queued(req.uid, req.t_submit, adm.t0)
+        with self._phases.span("engine.prefill", req.uid, ("stage", 0),
+                               args=lambda: {
+                                   "uid": req.uid, "slot": slot,
+                                   "tokens": slen, "reused": reused,
+                                   "plan": "mono"}):
+            if self._pager is not None:
+                nxt, self._cache = self._prefill_suffix_paged(
+                    self.params, self._cache, jnp.asarray(toks),
+                    jnp.int32(slot), jnp.int32(reused), jnp.int32(slen),
+                    jnp.asarray(ap.block_table)[None],
+                    jnp.asarray(ap.write_table)[None])
+                self._pager.commit(slot)  # pages landed: publish for reuse
+            else:
+                nxt, self._cache = self._prefill_slot(
+                    self.params, self._cache, jnp.asarray(toks),
+                    jnp.int32(slot), jnp.int32(slen))
             tok = int(self._sync(nxt)[0])  # host sync: prefill has run
-            self._prefill_window += time.perf_counter() - t0
-            if self._tr is not None:
-                self._tr.span(("stage", 0), "prefill", t0, args={
-                    "uid": req.uid, "slot": slot, "tokens": slen,
-                    "reused": reused, "plan": "mono"})
-        else:
-            toks = np.zeros((1, self._padded_len(plen)), np.int32)
-            toks[0, :plen] = req.prompt
-            t0 = time.perf_counter()
-            nxt, self._cache = self._prefill_slot(
-                self.params, self._cache, jnp.asarray(toks),
-                jnp.int32(slot), jnp.int32(plen))
-            tok = int(self._sync(nxt)[0])  # host sync: prefill has run
-            self._prefill_window += time.perf_counter() - t0
-            if self._tr is not None:
-                self._tr.span(("stage", 0), "prefill", t0, args={
-                    "uid": req.uid, "slot": slot, "tokens": plen,
-                    "reused": 0, "plan": "mono"})
         self.prefill_batch_sizes.append(1)
         # unpadded suffix tokens, same unit as plan-mode admission:
         # bucket padding is a jit-shape artifact, not prefill work
-        self.prefill_token_counts.append(
-            slen if self._pager is not None else plen)
+        self.prefill_token_counts.append(slen)
         self.prefill_chunk_counts.append(1)
         self._activate(req, slot, tok)
         return True
@@ -1240,20 +1236,23 @@ class ServingEngine:
         scatter at finish must not fail mid-flight)."""
         replica, local = self.plan.replica_of_slot(slot)
         reused = 0
-        if self._pager is not None:
-            ap = self._pager.admit(slot, req.prompt,
-                                   req.max_new_tokens + self._spec_k,
-                                   reuse_compute=self._suffix_reuse)
-            if ap is None:
-                return False
-            self._admit_plans[slot] = ap
-            reused = ap.reused_tokens
-            self._reserved.add(slot)
-            self._pf.admit(req, slot, replica, local, reused=reused,
-                           tables=(ap.block_table, ap.write_table))
-        else:
-            self._reserved.add(slot)
-            self._pf.admit(req, slot, replica, local)
+        with self._phases.span("engine.admit", req.uid) as adm:
+            if self._pager is not None:
+                ap = self._pager.admit(slot, req.prompt,
+                                       req.max_new_tokens + self._spec_k,
+                                       reuse_compute=self._suffix_reuse)
+                if ap is None:
+                    adm.name = "engine.admit.blocked"  # not an admission
+                    return False
+                self._admit_plans[slot] = ap
+                reused = ap.reused_tokens
+                self._reserved.add(slot)
+                self._pf.admit(req, slot, replica, local, reused=reused,
+                               tables=(ap.block_table, ap.write_table))
+            else:
+                self._reserved.add(slot)
+                self._pf.admit(req, slot, replica, local)
+        self._phases.queued(req.uid, req.t_submit, adm.t0)
         self.prefill_batch_sizes.append(1)
         self.prefill_token_counts.append(len(req.prompt) - reused)
         self.prefill_chunk_counts.append(
@@ -1281,9 +1280,9 @@ class ServingEngine:
         drained item's chunks ran on its admission runtime
         (drain-and-rebind), but the finished slot lands wherever the slot
         lives now (a new replica partition, or the monolithic cache)."""
+        from repro.models import transformer as T
         nxt, _ = (item.rt or self._rt).finish(self.params, item.final_hidden)
         tok = int(self._sync(nxt)[0])     # host sync: prefill has run
-        from repro.models import transformer as T
         if self.plan is not None:
             replica, local = self.plan.replica_of_slot(item.slot)
             if self.paged:
@@ -1355,21 +1354,16 @@ class ServingEngine:
     def _note_decode_util(self):
         """Per-replica occupied/capacity slot-step accounting at decode
         dispatch (always on — integer adds).  Mono counts as replica 0
-        over all slots.  Returns {replica: occupied_slots} for reuse by
-        the trace spans."""
+        over all slots."""
         if self.plan is None:
-            act = self.active
-            self._replica_busy[0] = self._replica_busy.get(0, 0) + act
+            self._replica_busy[0] = self._replica_busy.get(0, 0) + self.active
             self._replica_cap[0] = self._replica_cap.get(0, 0) + self.slots
-            return {0: act}
-        out = {}
+            return
         for r in range(self.plan.n_replicas):
             a, b = self.plan.replica_range(r)
-            act_r = sum(self._slot_req[s] is not None for s in range(a, b))
+            act_r = self._active_in(a, b)
             self._replica_busy[r] = self._replica_busy.get(r, 0) + act_r
             self._replica_cap[r] = self._replica_cap.get(r, 0) + (b - a)
-            out[r] = act_r
-        return out
 
     def _decode_once(self):
         """One batched decode step at per-slot positions.  Idle slots ride
@@ -1383,70 +1377,95 @@ class ServingEngine:
         batched VERIFY step instead: every slot scores a (K+1)-token
         window (current token + drafts) in one step, commits the
         accepted prefix, and rolls the rejected tail back."""
-        act = self.active                 # sampled at dispatch (see init)
-        racts = self._note_decode_util()
-        if self._spec_k:
-            drafts = self._draft_all()
+        ph = self._phases
+        with ph.span("engine.decode.prep") as prep:
+            act = self.active             # sampled at dispatch (see init)
+            self._note_decode_util()
+            drafts = self._draft_all() if self._spec_k else None
+            window = None
             if drafts is not None:
-                self._decode_verify(drafts)
-                self.decode_steps += 1
-                self._decode_slot_steps += act
-                self._occupied_step_sum += self.active
-                return
-        tr = self._tr
-        if self.plan is None:
-            td = time.perf_counter() if tr is not None else 0.0
-            bt = None
-            if self._pager is not None:
-                self._prepare_paged_writes(0, self.slots)
-                bt = jnp.asarray(self._pager.table_matrix())
-            nxt, _, self._cache = self.serve_step(
-                self.params, self._cache, jnp.asarray(self._cur),
-                jnp.asarray(self._pos), None, bt)
-            arr = self._sync(nxt)
-            if tr is not None:
-                tr.span(("replica", 0), "decode", td, args={
-                    "active": act, "slots": self.slots, "plan": "mono"})
-            now = time.perf_counter()
-            self._collect_decoded(arr, 0, self.slots, now)
-        else:
-            # dispatch every replica's step before syncing any result —
-            # the replicas are independent, so their device computations
-            # may overlap; only then round-trip the tokens to the host.
-            # (Paged replicas chain through the shared pool: each step
-            # consumes the pool leaves the previous one produced — the
-            # rebinding in _share_pool, serialized by data dependency.)
-            pending = []
-            for r in range(self.plan.n_replicas):
-                a, b = self.plan.replica_range(r)
-                if not any(self._slot_req[s] is not None
-                           for s in range(a, b)):
-                    continue
-                td = time.perf_counter() if tr is not None else 0.0
-                bt = None
-                if self.paged:
-                    self._prepare_paged_writes(a, b)
-                    bt = jnp.asarray(self._pager.table_matrix()[a:b])
-                nxt, self._caches[r] = self._rt.decode_step(
-                    self.params, self._caches[r],
-                    jnp.asarray(self._cur[a:b]),
-                    jnp.asarray(self._pos[a:b]), bt)
-                self._share_pool(r)
-                pending.append((nxt, a, b, r, td))
-            arrs = []
-            for nxt, a, b, r, td in pending:
-                arr = self._sync(nxt)
-                if tr is not None:
-                    tr.span(("replica", r), "decode", td, args={
-                        "active": racts.get(r, 0), "slots": b - a,
-                        "plan": self.plan.label})
-                arrs.append((arr, a, b))
+                window = np.zeros((self.slots, self._spec_k + 1), np.int32)
+                window[:, 0] = self._cur[:, 0]
+                for slot, d in drafts.items():
+                    if d:
+                        window[slot, 1:1 + len(d)] = d
+            pending = self._dispatch_steps(window)
+        arrs = []
+        for out, a, b, r in pending:
+            # an attached Tracer records each replica's step from its
+            # dispatch through its readback, as "decode" or "verify"
+            if window is None:
+                wait, args = "engine.decode.wait", lambda: {
+                    "active": self._active_in(a, b), "slots": b - a,
+                    "plan": self._label}
+            else:
+                wait, args = "engine.verify", lambda: {
+                    "window": window.shape[1], "plan": self._label,
+                    "drafted": sum(len(d) for s, d in drafts.items()
+                                   if a <= s < b)}
+            with ph.span(wait, None, ("replica", r), args, prep.t0):
+                arrs.append((self._sync(out), a, b))
+        with ph.span("engine.decode.collect"):
             now = time.perf_counter()
             for arr, a, b in arrs:
-                self._collect_decoded(arr, a, b, now)
-        self.decode_steps += 1
-        self._decode_slot_steps += act
-        self._occupied_step_sum += self.active
+                if window is None:
+                    self._collect_decoded(arr, a, b, now)
+                else:
+                    self._collect_verified(window, arr, drafts, a, b, now)
+            if window is not None:
+                self.spec_steps += 1
+            self.decode_steps += 1
+            self._decode_slot_steps += act
+            self._occupied_step_sum += self.active
+
+    def _busy_replicas(self):
+        """(replica, first slot, end) of every decode replica holding an
+        active slot; monolithic decode is replica 0 over all slots."""
+        if self.plan is None:
+            return [(0, 0, self.slots)]
+        out = []
+        for r in range(self.plan.n_replicas):
+            a, b = self.plan.replica_range(r)
+            if self._active_in(a, b):
+                out.append((r, a, b))
+        return out
+
+    def _dispatch_steps(self, window=None):
+        """Dispatch one decode step — one verify step of the drafted
+        ``window`` when given — on every busy replica, reading nothing
+        back; returns ``[(output, first, end, replica)]``.  Every
+        replica's step is dispatched before any result is synced: the
+        replicas are independent, so their device computations may
+        overlap.  (Paged replicas chain through the shared pool: each
+        step consumes the pool leaves the previous one produced — the
+        rebinding in _share_pool, serialized by data dependency.)"""
+        out = []
+        for r, a, b in self._busy_replicas():
+            bt = None
+            if self._pager is not None:
+                if window is None:
+                    self._prepare_paged_writes(a, b)
+                else:
+                    self._prepare_verify_writes(a, b, window.shape[1])
+                bt = jnp.asarray(self._pager.table_matrix()[a:b])
+            inp = jnp.asarray(self._cur[a:b] if window is None
+                              else window[a:b])
+            pos = jnp.asarray(self._pos[a:b])
+            if self.plan is None:
+                if window is None:
+                    nxt, _, self._cache = self.serve_step(
+                        self.params, self._cache, inp, pos, None, bt)
+                else:
+                    nxt, self._cache = self._verify_step(
+                        self.params, self._cache, inp, pos, bt)
+            else:
+                step = (self._rt.decode_step if window is None
+                        else self._rt.verify_step)
+                nxt, self._caches[r] = step(self.params, self._caches[r],
+                                            inp, pos, bt)
+                self._share_pool(r)
+            out.append((nxt, a, b, r))
+        return out
 
     # ---- overlapped (async) decode ---------------------------------------
     def _dispatch_decode(self):
@@ -1470,52 +1489,34 @@ class ServingEngine:
         device transfer may still be reading its source when this method
         returns, and ``_pos``/``_cur`` are mutated right after dispatch."""
         act = self.active
-        racts = self._note_decode_util()
-        tr = self._tr
+        self._note_decode_util()
         if self._cur_dev is None:
             self._cur_dev = _upload(self._cur)
         arrs = []
-        rng = []
-        if self.plan is None:
-            td = time.perf_counter() if tr is not None else 0.0
-            bt = None
-            if self._pager is not None:
-                self._prepare_paged_writes(0, self.slots)
-                bt = jnp.asarray(self._pager.table_matrix())
-            nxt, _, self._cache = self.serve_step(
-                self.params, self._cache, self._cur_dev,
-                _upload(self._pos), None, bt)
-            self._cur_dev = nxt
-            if tr is not None:
-                tr.span(("replica", 0), "decode_dispatch", td, args={
-                    "active": act, "slots": self.slots, "plan": "mono"})
-            arrs.append((nxt, 0, self.slots))
-            rng.append((0, self.slots))
-        else:
-            for r in range(self.plan.n_replicas):
-                a, b = self.plan.replica_range(r)
-                if not any(self._slot_req[s] is not None
-                           for s in range(a, b)):
-                    continue
-                td = time.perf_counter() if tr is not None else 0.0
+        for r, a, b in self._busy_replicas():
+            with self._phases.span(
+                    "engine.decode.dispatch", None, ("replica", r),
+                    args=lambda: {"active": self._active_in(a, b),
+                                  "slots": b - a, "plan": self._label}):
                 bt = None
-                if self.paged:
+                if self._pager is not None:
                     self._prepare_paged_writes(a, b)
                     bt = jnp.asarray(self._pager.table_matrix()[a:b])
-                nxt, self._caches[r] = self._rt.decode_step(
-                    self.params, self._caches[r], self._cur_dev[a:b],
-                    _upload(self._pos[a:b]), bt)
-                self._share_pool(r)
-                self._cur_dev = self._cur_dev.at[a:b].set(nxt)
-                if tr is not None:
-                    tr.span(("replica", r), "decode_dispatch", td, args={
-                        "active": racts.get(r, 0), "slots": b - a,
-                        "plan": self.plan.label})
-                arrs.append((nxt, a, b))
-                rng.append((a, b))
+                if self.plan is None:
+                    nxt, _, self._cache = self.serve_step(
+                        self.params, self._cache, self._cur_dev,
+                        _upload(self._pos), None, bt)
+                    self._cur_dev = nxt
+                else:
+                    nxt, self._caches[r] = self._rt.decode_step(
+                        self.params, self._caches[r], self._cur_dev[a:b],
+                        _upload(self._pos[a:b]), bt)
+                    self._share_pool(r)
+                    self._cur_dev = self._cur_dev.at[a:b].set(nxt)
+            arrs.append((nxt, a, b))
         entries = []
         in_toks: Dict[int, Optional[int]] = {}
-        for a, b in rng:
+        for _, a, b in arrs:
             for slot in range(a, b):
                 req = self._slot_req[slot]
                 if req is None:
@@ -1533,44 +1534,42 @@ class ServingEngine:
         self.decode_steps += 1
         self._decode_slot_steps += act
 
-    def _drain_one(self):
+    def _drain_one(self, span: str = "engine.drain"):
         """Read back the OLDEST in-flight step and do everything the sync
         path does after a step: extend block chains with the step's input
         tokens, append the output tokens, retire EOS/budget slots (one
         tick later than sync mode — the per-request token STREAMS are
         identical), and hand each drained token to the next in-flight
-        record, whose input it is."""
-        td = time.perf_counter() if self._tr is not None else 0.0
+        record, whose input it is.  ``span`` names the phase."""
         rec = self._inflight.pop(0)
-        arrs = [(self._sync(nxt), a, b) for nxt, a, b in rec.arrs]
-        now = time.perf_counter()
-        nxt_rec = self._inflight[0] if self._inflight else None
-        nxt_req = ({s: r for s, r, _ in nxt_rec.entries}
-                   if nxt_rec is not None else {})
-        for arr, a, b in arrs:
-            for slot, req, pos_snap in rec.entries:
-                if not (a <= slot < b):
-                    continue
-                if self._slot_req[slot] is not req:
-                    continue      # slot retired mid-flight: garbage step
-                pager, local = self._pager_of(slot)
-                if pager is not None:
-                    tok_in = rec.in_toks[slot]
-                    assert tok_in is not None, slot
-                    pager.note_written(local, tok_in, pos_snap)
-                tok = int(arr[slot - a, 0])
-                req.out_tokens.append(tok)
-                self._cur[slot, 0] = tok
-                self._cur_known[slot] = True
-                if nxt_req.get(slot) is req:
-                    nxt_rec.in_toks[slot] = tok
-                self.decode_tokens += 1
-                self._maybe_retire(slot, now, pos=pos_snap + 1)
-        self._occupied_step_sum += self.active
-        if self._tr is not None:
-            self._tr.span("tick", "drain", td, args={
+        with self._phases.span(span, args=lambda: {
                 "slots_drained": len(rec.entries),
-                "inflight": len(self._inflight)})
+                "inflight": len(self._inflight)}):
+            arrs = [(self._sync(nxt), a, b) for nxt, a, b in rec.arrs]
+            now = time.perf_counter()
+            nxt_rec = self._inflight[0] if self._inflight else None
+            nxt_req = ({s: r for s, r, _ in nxt_rec.entries}
+                       if nxt_rec is not None else {})
+            for arr, a, b in arrs:
+                for slot, req, pos_snap in rec.entries:
+                    if not (a <= slot < b):
+                        continue
+                    if self._slot_req[slot] is not req:
+                        continue      # slot retired mid-flight: garbage step
+                    pager, local = self._pager_of(slot)
+                    if pager is not None:
+                        tok_in = rec.in_toks[slot]
+                        assert tok_in is not None, slot
+                        pager.note_written(local, tok_in, pos_snap)
+                    tok = int(arr[slot - a, 0])
+                    req.out_tokens.append(tok)
+                    self._cur[slot, 0] = tok
+                    self._cur_known[slot] = True
+                    if nxt_req.get(slot) is req:
+                        nxt_rec.in_toks[slot] = tok
+                    self.decode_tokens += 1
+                    self._maybe_retire(slot, now, pos=pos_snap + 1)
+            self._occupied_step_sum += self.active
 
     # ---- speculative decode ----------------------------------------------
     def _draft_all(self):
@@ -1621,67 +1620,6 @@ class ServingEngine:
                         self._caches[r] = self._copy_pages(
                             self._caches[r], jnp.int32(src), jnp.int32(dst))
                         self._share_pool(r)
-
-    def _decode_verify(self, drafts: Dict[int, List[int]]):
-        """One speculative tick: write + score each slot's (K+1)-token
-        window in a single batched step, then accept the longest prefix
-        of drafts the target model agrees with (greedy verify), roll the
-        rejected tail back, and continue from the last accepted token.
-        Slots that drafted nothing degenerate to plain decode (their
-        window is just the current token plus ignored padding)."""
-        sw = self._spec_k + 1
-        window = np.zeros((self.slots, sw), np.int32)
-        window[:, 0] = self._cur[:, 0]
-        for slot, d in drafts.items():
-            if d:
-                window[slot, 1:1 + len(d)] = d
-        tr = self._tr
-        if self.plan is None:
-            td = time.perf_counter() if tr is not None else 0.0
-            bt = None
-            if self._pager is not None:
-                self._prepare_verify_writes(0, self.slots, sw)
-                bt = jnp.asarray(self._pager.table_matrix())
-            outs, self._cache = self._verify_step(
-                self.params, self._cache, jnp.asarray(window),
-                jnp.asarray(self._pos), bt)
-            arr0 = self._sync(outs)
-            if tr is not None:
-                tr.span(("replica", 0), "verify", td, args={
-                    "window": sw, "drafted": sum(map(len, drafts.values())),
-                    "plan": "mono"})
-            now = time.perf_counter()
-            self._collect_verified(window, arr0, drafts,
-                                   0, self.slots, now)
-        else:
-            pending = []
-            for r in range(self.plan.n_replicas):
-                a, b = self.plan.replica_range(r)
-                if not any(self._slot_req[s] is not None
-                           for s in range(a, b)):
-                    continue
-                td = time.perf_counter() if tr is not None else 0.0
-                bt = None
-                if self.paged:
-                    self._prepare_verify_writes(a, b, sw)
-                    bt = jnp.asarray(self._pager.table_matrix()[a:b])
-                outs, self._caches[r] = self._rt.verify_step(
-                    self.params, self._caches[r],
-                    jnp.asarray(window[a:b]),
-                    jnp.asarray(self._pos[a:b]), bt)
-                self._share_pool(r)
-                pending.append((outs, a, b, r, td))
-            arrs = []
-            for o, a, b, r, td in pending:
-                arr = self._sync(o)
-                if tr is not None:
-                    tr.span(("replica", r), "verify", td, args={
-                        "window": sw, "plan": self.plan.label})
-                arrs.append((arr, a, b))
-            now = time.perf_counter()
-            for arr, a, b in arrs:
-                self._collect_verified(window, arr, drafts, a, b, now)
-        self.spec_steps += 1
 
     def _collect_verified(self, window, outs, drafts, a: int, b: int,
                           now: float):

@@ -1,5 +1,6 @@
-"""Observability layer: tracer ring, metrics registry, Perfetto export,
-engine integration (no-op guarantee, parity, utilization, snapshots)."""
+"""Observability layer: phase recorder, tracer ring, metrics registry,
+Perfetto export, engine integration (no-op guarantee, parity,
+utilization, snapshots)."""
 import json
 
 import numpy as np
@@ -10,10 +11,9 @@ import jax
 import repro.obs.trace as trace_mod
 from repro.configs import REGISTRY, reduced
 from repro.models import build_model
-from repro.obs import (MetricsRegistry, TraceConfig, Tracer, TrafficSnapshot,
-                       to_perfetto, validate_perfetto, write_metrics,
-                       write_trace)
-from repro.obs.derive import utilization_from_trace
+from repro.obs import (PHASE_BUCKETS, MetricsRegistry, PhaseRecorder,
+                       TraceConfig, Tracer, TrafficSnapshot, to_perfetto,
+                       validate_perfetto, write_metrics, write_trace)
 from repro.plan import lower_serving, uniform_plan
 from repro.serving import AdaptiveConfig, Request, ServingEngine
 
@@ -184,9 +184,6 @@ def test_trace_spans_carry_stage_replica_and_request_tags(setup):
     assert dec_tracks == {("replica", 0), ("replica", 1)}
     admits = [r for r in recs if r[0] == "X" and r[2] == "admit"]
     assert admits and all(r[6] == r[5]["uid"] for r in admits)  # flow id
-    util = utilization_from_trace(eng._tr)
-    assert util["window_s"] > 0
-    assert set(util["replica_busy_frac"]) == {0, 1}
 
 
 def test_utilization_stats_present_and_bounded(setup):
@@ -269,3 +266,173 @@ def test_export_metrics_and_write_paths(setup, tmp_path):
     # write_trace refuses on an untraced engine
     with pytest.raises(ValueError):
         eng.write_trace(str(tmp_path / "t.json"))
+
+
+# ---------------------------------------------------------------------------
+# the phase recorder: the engine's phase clock as spans
+
+
+@pytest.mark.parametrize("mode", ["mono-paged", "plan-paged"])
+def test_phase_spans_tile_each_tick_and_sum_to_the_buckets(setup, mode):
+    cfg, model, params = setup
+    kw = dict(paged=True, page_size=4)
+    if mode.startswith("plan"):
+        kw["plan"] = _plan(cfg)
+    eng = ServingEngine(model, params, slots=2, max_seq=48, **kw)
+    _drive(eng)                      # 3 requests over 2 slots: one queues
+    st = eng.stats()
+    spans = st["spans"]
+    assert st["spans_dropped"] == 0
+    assert {s[0] for s in spans} <= set(PHASE_BUCKETS)
+    ticks = [s for s in spans if s[0] == "engine.tick"]
+    assert len(ticks) == st["ticks"]
+    assert [s[3] for s in ticks] == list(range(len(ticks)))
+    def within(a, b):
+        return a is not b and b[1] <= a[1] and a[2] <= b[2]
+
+    for k, (_, t0, t1, _, _) in enumerate(ticks):
+        # every phase of tick k lies inside it; two phases either nest or
+        # follow one another, and those that nest in none are bucketed
+        inner = sorted((s for s in spans if s[3] == k
+                        and s[0] != "engine.tick"),
+                       key=lambda s: (s[1], -s[2]))
+        assert inner and all(t0 <= s[1] <= s[2] <= t1 for s in inner)
+        for i, a in enumerate(inner):
+            assert all(b[1] >= a[2] or b[2] <= a[2] for b in inner[i + 1:])
+        top = [s for s in inner if not any(within(s, o) for o in inner)]
+        assert all(PHASE_BUCKETS[s[0]] is not None for s in top)
+        assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+    # the admission loop holds each request's admit and prefill
+    loops = [s for s in spans if s[0] == "engine.admission"]
+    if mode.startswith("mono"):
+        assert all(any(within(s, o) for o in loops) for s in spans
+                   if s[0] in ("engine.admit", "engine.prefill"))
+    # a bucket is the sum of its spans' own time: their length less
+    # that of the bucketed spans nested in them
+    bucketed = [s for s in spans if PHASE_BUCKETS[s[0]] is not None]
+    sums = dict.fromkeys(st["phase_time_s"], 0.0)
+    for s in bucketed:
+        own = s[2] - s[1] - sum(c[2] - c[1] for c in bucketed
+                                if within(c, s))
+        sums[PHASE_BUCKETS[s[0]]] += own * 1e-9
+    for b in ("admission", "prefill", "decode", "replan"):
+        assert sums[b] == pytest.approx(st["phase_time_s"][b], abs=1e-6), b
+    assert st["phase_time_s"]["decode"] > 0
+    # one queue span per request, from its submit to its admission
+    queued = [s for s in spans if s[0] == "engine.queue"]
+    assert sorted(s[4] for s in queued) == [0, 1, 2]
+    admit_start = {s[4]: s[1] for s in spans if s[0] == "engine.admit"}
+    assert all(s[2] == admit_start[s[4]] for s in queued)
+    assert all(s[1] <= s[2] for s in queued)
+
+
+def test_a_request_still_queued_counts_its_wait_so_far(setup):
+    cfg, model, params = setup
+    eng = ServingEngine(model, params, slots=1, max_seq=48)
+    for uid in range(2):
+        eng.submit(Request(uid, np.arange(1, 6, dtype=np.int32), 4))
+    eng.tick()                                     # admits uid 0 only
+    spans = eng.stats()["spans"]
+    queued = {s[4]: s for s in spans if s[0] == "engine.queue"}
+    assert set(queued) == {0, 1}
+    tick_end = max(s[2] for s in spans if s[0] == "engine.tick")
+    assert queued[1][2] == tick_end and queued[1][1] < tick_end
+    eng.reset_stats()
+    assert [s[0] for s in eng.stats()["spans"]] == ["engine.queue"]
+
+
+def test_phase_log_wraps_and_counts_drops(setup):
+    rec = PhaseRecorder(capacity=4)
+    for i in range(10):
+        with rec.span("engine.decode", uid=i):
+            pass
+    spans = rec.spans()
+    assert rec.dropped == 6 and len(spans) == 4
+    assert [s[4] for s in spans] == [6, 7, 8, 9]   # newest kept, in order
+    assert rec.time["decode"] > 0
+    rec.reset()
+    assert rec.spans() == [] and rec.dropped == 0
+    assert rec.time["decode"] == 0.0
+    with pytest.raises(ValueError):
+        PhaseRecorder(capacity=0)
+    # through the engine: a small log wraps, and stats() says so
+    cfg, model, params = setup
+    eng = ServingEngine(model, params, slots=2, max_seq=48)
+    eng._phases = PhaseRecorder(capacity=8)
+    _drive(eng)
+    st = eng.stats()
+    assert st["spans_dropped"] > 0 and len(st["spans"]) == 8
+
+
+def test_phase_spans_go_to_an_attached_tracer_only(setup):
+    cfg, model, params = setup
+    before = trace_mod.RECORDS_TOTAL
+    eng = ServingEngine(model, params, slots=2, max_seq=48)
+    _drive(eng)
+    assert trace_mod.RECORDS_TOTAL == before
+    assert eng.stats()["spans"]
+    eng = ServingEngine(model, params, slots=2, max_seq=48, trace=True)
+    _drive(eng)
+    recs = [r for r in eng._tr.records() if r[0] == "X"]
+    names = {r[2] for r in recs}
+    assert {"admission", "prefill", "decode", "queue", "engine.admit",
+            "engine.decode", "engine.decode.prep", "engine.decode.collect",
+            "engine.tick"} <= names
+    # the spans the Tracer had before keep their extent and their args
+    by = {}
+    for r in recs:
+        by.setdefault(r[2], []).append(r)
+    assert all(set(r[5]) == {"queued", "admitted", "plan"}
+               for r in by["admission"])
+    assert all(set(r[5]) == {"uid", "slot", "tokens", "reused", "plan"}
+               and r[1] == ("stage", 0) for r in by["prefill"])
+    # decode: one replica's step, from its dispatch through its readback
+    preps = sorted(r[3] for r in by["engine.decode.prep"])
+    assert sorted(r[3] for r in by["decode"]) == preps
+    assert all(set(r[5]) == {"active", "slots", "plan"}
+               and r[1] == ("replica", 0) for r in by["decode"])
+
+
+def test_a_bucketed_span_accrues_its_own_time():
+    rec = PhaseRecorder()
+    with rec.span("engine.admission"):
+        with rec.span("engine.admit"):
+            pass
+        with rec.span("engine.prefill"):
+            pass
+    (_, a0, a1, _, _), (_, p0, p1, _, _) = [
+        s for s in rec.spans() if s[0] != "engine.admit"][::-1]
+    assert rec.time["prefill"] == pytest.approx((p1 - p0) * 1e-9, abs=1e-12)
+    assert rec.time["admission"] == pytest.approx(
+        (a1 - a0 - (p1 - p0)) * 1e-9, abs=1e-12)
+
+
+def test_an_admission_the_pool_cannot_supply_is_logged_apart(setup):
+    cfg, model, params = setup
+    # room for one request's pages at a time: the second waits for them
+    eng = ServingEngine(model, params, slots=2, max_seq=48, paged=True,
+                        page_size=4, num_blocks=4)
+    _drive(eng, n=2)
+    spans = eng.stats()["spans"]
+    names = [s[0] for s in spans]
+    assert names.count("engine.admit") == 2
+    assert names.count("engine.admit.blocked") > 0
+    assert sorted(s[4] for s in spans if s[0] == "engine.queue") == [0, 1]
+
+
+def test_a_replan_keeps_its_tracer_record(setup):
+    cfg, model, params = setup
+    plan = _plan(cfg)
+    eng = ServingEngine(model, params, slots=2, max_seq=48, plan=plan,
+                        paged=True, page_size=4, trace=True)
+    for i in range(2):
+        eng.submit(Request(i, np.arange(1, 7, dtype=np.int32), 6))
+    for _ in range(4):
+        eng.tick()
+    eng.replan(None)
+    eng.run()
+    swaps = [r for r in eng._tr.records() if r[0] == "X" and r[2] == "replan"]
+    assert len(swaps) == 1 and swaps[0][5] == {
+        "from": plan.label, "to": "mono", "migrations": eng.migrations,
+        "migration_copies": eng.migration_copies}
+    assert "engine.replan" in {s[0] for s in eng.stats()["spans"]}

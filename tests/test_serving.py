@@ -165,12 +165,13 @@ def test_phase_time_partition_sums_to_wall_including_idle():
         time.sleep(0.002)            # caller-side gap -> idle bucket
     pt = eng.stats()["phase_time_s"]
     assert pt["idle"] > 0.0
-    wall = eng._t_tick_end - eng._t_first_tick
+    ph = eng._phases
+    wall = (ph.last_tick_end_ns - ph.first_tick_ns) * 1e-9
     partition = (pt["admission"] + pt["prefill"] + pt["decode"]
                  + pt["replan"] + pt["idle"])
     assert partition == pytest.approx(wall, rel=0.02, abs=1e-4)
     eng.reset_stats()
-    assert eng._t_first_tick is None and eng._t_tick_end is None
+    assert ph.first_tick_ns is None and ph.last_tick_end_ns is None
     assert eng.stats()["phase_time_s"]["idle"] == 0.0
 
 
@@ -188,9 +189,12 @@ def test_stats_repeat_calls_are_idempotent():
         assert a == b
         assert a["cache"] == b["cache"]
         assert a["utilization"] == b["utilization"]
+        assert a["spans"] == b["spans"] and a["spans"]
         # a mutated copy must not leak back into the engine's counters
         a["cache"]["layout"] = "mutated"
         a["utilization"]["pipeline_ticks"] = -1
+        a["spans"].append(("engine.tick", 0, 1, None, None))
+        a["phase_time_s"]["decode"] = -1.0
         c = eng.stats()
         assert c["cache"]["layout"] != "mutated"
         assert c == b
