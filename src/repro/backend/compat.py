@@ -7,6 +7,7 @@ There is one branch per surface: the API of jax 0.9.0.
 
 Surfaces:
   * ``pltpu.CompilerParams`` and the Pallas TPU memory spaces
+  * DMA semaphores and ``pltpu.make_async_copy`` (manual HBM -> VMEM)
   * ``jax.make_mesh`` / ``Mesh`` with explicit ``AxisType.Auto`` axes
   * ``jax.set_mesh`` as the ambient-mesh context
   * ``jax.shard_map(..., axis_names=...)`` (partial-manual)
@@ -78,6 +79,27 @@ def vmem_scratch(shape, dtype):
 def smem_scratch(shape, dtype):
     """SMEM scratch allocation for ``pallas_call(scratch_shapes=...)``."""
     return _pltpu().SMEM(tuple(shape), dtype)
+
+
+def hbm_spec():
+    """Block spec that leaves an operand in HBM: the kernel sees the
+    whole array and moves what it needs itself with ``async_copy``.
+    (``pl.ANY`` would let the compiler put a small operand in VMEM,
+    whose padded tiles then refuse the per-page DMA slices.)"""
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec(memory_space=_pltpu().HBM)
+
+
+def dma_semaphores(shape):
+    """Array of DMA semaphores for ``pallas_call(scratch_shapes=...)``."""
+    return _pltpu().SemaphoreType.DMA(tuple(shape))
+
+
+def async_copy(src_ref, dst_ref, sem_ref):
+    """``pltpu.make_async_copy``: a DMA descriptor ``src -> dst`` that
+    signals ``sem``; ``.start()`` issues it, ``.wait()`` blocks until
+    it has landed (a descriptor of the same shapes may wait for it)."""
+    return _pltpu().make_async_copy(src_ref, dst_ref, sem_ref)
 
 
 def prefetch_grid_spec(*, num_scalar_prefetch, grid, in_specs, out_specs,
@@ -223,7 +245,7 @@ def varying_axes(*trees) -> Tuple[str, ...]:
 __all__ = [
     "SUPPORTED_RANGE", "jax_version", "backend", "on_tpu",
     "tpu_compiler_params", "vmem_scratch", "smem_scratch",
-    "prefetch_grid_spec",
+    "hbm_spec", "dma_semaphores", "async_copy", "prefetch_grid_spec",
     "make_mesh", "make_mesh_on", "use_mesh", "make_abstract_mesh",
     "mesh_axis_size",
     "shard_map", "pcast_varying", "varying_axes", "kernel_out",

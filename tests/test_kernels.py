@@ -152,20 +152,24 @@ def test_linear_scan_matches_chunked_model_path():
 # fused paged decode (RoPE + page write + attention in one kernel)
 # ---------------------------------------------------------------------------
 
-def _fused_setup(seed, *, b=3, h=4, hk=2, d=128, page=32, nb=3,
+def _fused_setup(seed, *, b=3, h=4, hk=2, d=128, page=32, nb=3, pos=None,
                  kv_dtype=jnp.float32, int8=False):
     """Random fused-decode operands with DISJOINT per-slot page tables
     (+ a trailing sink page).  Disjointness mirrors the engine's
     ownership invariant — write pages are exclusively owned — which the
     in-place aliased kernel writes rely on; colliding random tables
-    would interleave one slot's write with another's gather."""
+    would interleave one slot's write with another's gather.  ``pos``
+    (one write position per slot) overrides ``b``."""
     r = _rng(seed)
+    if pos is None:
+        pos = [page - 1, page + 5, 2 * page + 17][:b]
+    b = len(pos)
     n = b * nb + 1                                   # + sink page
     q = jnp.asarray(r.standard_normal((b, hk, h // hk, d)), jnp.float32)
     kn = jnp.asarray(r.standard_normal((b, hk, d)), jnp.float32)
     vn = jnp.asarray(r.standard_normal((b, hk, d)), jnp.float32)
     bt = jnp.asarray(r.permutation(b * nb).reshape(b, nb), jnp.int32)
-    pos = jnp.asarray([page - 1, page + 5, 2 * page + 17][:b], jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
     if int8:
         kf = r.standard_normal((n, hk, page, d))
         vf = r.standard_normal((n, hk, page, d))
@@ -175,6 +179,33 @@ def _fused_setup(seed, *, b=3, h=4, hk=2, d=128, page=32, nb=3,
     kp = jnp.asarray(r.standard_normal((n, hk, page, d)), kv_dtype)
     vp = jnp.asarray(r.standard_normal((n, hk, page, d)), kv_dtype)
     return q, kn, vn, kp, vp, bt, pos, None, None
+
+
+def _fused_shape(name, page):
+    """Fused-decode shapes at ``page`` tokens per page; the kernel walks
+    blocks of 128 tokens (``decode_pages_per_block``).  ``small`` is one
+    partial block per slot; ``edges`` puts slots on both sides of a
+    block edge (127, 128), at position 0 (an idle slot) and at max_seq-1
+    over a table of 2.5 blocks; ``gqa7`` is yi-34b's 56 query heads over
+    8 kv heads, on both sides of the second block edge (255, 256), over
+    a table that ends inside a block."""
+    if name == "small":
+        return {"page": page}
+    if name == "edges":
+        nb = 320 // page
+        return {"page": page, "nb": nb, "pos": (127, 128, 0, nb * page - 1)}
+    nb = 272 // page + 1
+    return {"page": page, "nb": nb, "h": 56, "hk": 8,
+            "pos": (255, 256, 0, 129, nb * page - 1)}
+
+
+FUSED_SHAPES = ("small", "edges", "gqa7")
+
+# the reference as the model step runs it, jitted: the RoPE frequency
+# table then folds the way the kernel's does (eager, XLA's own ``pow``
+# differs in the last bit, which positions in the hundreds amplify)
+_fused_ref = jax.jit(R.fused_paged_decode_ref,
+                     static_argnames=("theta", "softcap"))
 
 
 def test_decode_rope_ref_matches_model_apply_rope_bitwise():
@@ -212,35 +243,36 @@ def test_fused_paged_decode_ref_composes_unfused_ops_bitwise():
     assert np.array_equal(np.asarray(out), np.asarray(eout))
 
 
+@pytest.mark.parametrize("shape", FUSED_SHAPES)
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("softcap", [0.0, 30.0])
-def test_fused_paged_decode_kernel_matches_ref(softcap):
+def test_fused_paged_decode_kernel_matches_ref(softcap, kv_dtype, shape):
     from repro.kernels.paged_attention import fused_paged_decode_grouped
-    q, kn, vn, kp, vp, bt, pos, _, _ = _fused_setup(32)
+    page = 32 if shape == "small" else 16
+    q, kn, vn, kp, vp, bt, pos, _, _ = _fused_setup(
+        32, kv_dtype=kv_dtype, **_fused_shape(shape, page))
     kw = dict(theta=10000.0, softcap=softcap)
-    ro, rkp, rvp, _, _ = R.fused_paged_decode_ref(q, kn, vn, kp, vp, bt,
-                                                  pos, **kw)
+    ro, rkp, rvp, _, _ = _fused_ref(q, kn, vn, kp, vp, bt, pos, **kw)
     io_, ikp, ivp, _, _ = fused_paged_decode_grouped(
         q, kn, vn, kp, vp, bt, pos, interpret=True, **kw)
+    assert ikp.dtype == kv_dtype
     np.testing.assert_allclose(np.asarray(io_), np.asarray(ro),
                                atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(ikp), np.asarray(rkp),
+    np.testing.assert_allclose(np.asarray(ikp, np.float32),
+                               np.asarray(rkp, np.float32),
                                atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(ivp), np.asarray(rvp),
+    np.testing.assert_allclose(np.asarray(ivp, np.float32),
+                               np.asarray(rvp, np.float32),
                                atol=2e-5, rtol=2e-5)
 
 
-def test_fused_paged_decode_kernel_int8_matches_ref():
+def _assert_int8_fused_matches(got, want):
     """int8 pools: the kernel's in-kernel quantization must produce the
     same int8 rows and scales as the reference (both quantize the same
     roped fp row with the same symmetric rule), and the attention output
     must match on the dequantized values."""
-    from repro.kernels.paged_attention import fused_paged_decode_grouped
-    q, kn, vn, kp, vp, bt, pos, ks, vs = _fused_setup(33, int8=True)
-    kw = dict(theta=10000.0, k_scales=ks, v_scales=vs)
-    ro, rkp, rvp, rks, rvs = R.fused_paged_decode_ref(q, kn, vn, kp, vp,
-                                                      bt, pos, **kw)
-    io_, ikp, ivp, iks, ivs = fused_paged_decode_grouped(
-        q, kn, vn, kp, vp, bt, pos, interpret=True, **kw)
+    io_, ikp, ivp, iks, ivs = got
+    ro, rkp, rvp, rks, rvs = want
     assert ikp.dtype == jnp.int8 and iks.dtype == jnp.float32
     np.testing.assert_array_equal(np.asarray(ikp), np.asarray(rkp))
     np.testing.assert_array_equal(np.asarray(ivp), np.asarray(rvp))
@@ -249,6 +281,66 @@ def test_fused_paged_decode_kernel_int8_matches_ref():
     np.testing.assert_allclose(np.asarray(ivs), np.asarray(rvs),
                                atol=1e-7, rtol=1e-6)
     np.testing.assert_allclose(np.asarray(io_), np.asarray(ro),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("shape", FUSED_SHAPES)
+def test_fused_paged_decode_kernel_int8_matches_ref(shape):
+    """int8 pools (page 32, the int8 tile) at every fused shape."""
+    from repro.kernels.paged_attention import fused_paged_decode_grouped
+    q, kn, vn, kp, vp, bt, pos, ks, vs = _fused_setup(
+        33, int8=True, **_fused_shape(shape, 32))
+    kw = dict(theta=10000.0, k_scales=ks, v_scales=vs)
+    _assert_int8_fused_matches(
+        fused_paged_decode_grouped(q, kn, vn, kp, vp, bt, pos,
+                                   interpret=True, **kw),
+        _fused_ref(q, kn, vn, kp, vp, bt, pos, **kw))
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_fused_paged_decode_kernel_stops_at_each_slot_length(kv):
+    """Every row past each slot's write position, and every page no slot
+    maps, holds NaN (on int8 pools: NaN scales).  The kernel must never
+    let one reach the output: it walks each slot's live pages only and
+    masks the rest.  Output equals the reference on the clean pools; the
+    pools equal the reference's writes into the NaN pools (NaN rows
+    pass through untouched)."""
+    from repro.kernels.paged_attention import fused_paged_decode_grouped
+    page = 32 if kv == "int8" else 16
+    q, kn, vn, kp, vp, bt, pos, ks, vs = _fused_setup(
+        36, int8=kv == "int8", **_fused_shape("edges", page))
+    n = kp.shape[0]
+    dead = np.ones((n, page), bool)     # (physical page, row): unmapped
+                                        # or past its slot's position
+    for b_, p_ in enumerate(np.asarray(pos)):
+        for j, phys in enumerate(np.asarray(bt[b_])):
+            dead[phys] = j * page + np.arange(page) > p_
+    dead = jnp.asarray(dead)[:, None, :]     # (N, 1, P): all kv heads
+    kw = dict(theta=10000.0)
+    if kv == "int8":
+        nks = jnp.where(dead, jnp.nan, ks)
+        nvs = jnp.where(dead, jnp.nan, vs)
+        got = fused_paged_decode_grouped(q, kn, vn, kp, vp, bt, pos,
+                                         interpret=True, k_scales=nks,
+                                         v_scales=nvs, **kw)
+        want = _fused_ref(q, kn, vn, kp, vp, bt, pos, k_scales=nks,
+                          v_scales=nvs, **kw)
+        clean = _fused_ref(q, kn, vn, kp, vp, bt, pos, k_scales=ks,
+                           v_scales=vs, **kw)
+        _assert_int8_fused_matches(got, (clean[0],) + tuple(want[1:]))
+        return
+    nkp = jnp.where(dead[..., None], jnp.nan, kp)
+    nvp = jnp.where(dead[..., None], jnp.nan, vp)
+    io_, ikp, ivp, _, _ = fused_paged_decode_grouped(
+        q, kn, vn, nkp, nvp, bt, pos, interpret=True, **kw)
+    ro, *_ = _fused_ref(q, kn, vn, kp, vp, bt, pos, **kw)
+    _, rkp, rvp, _, _ = _fused_ref(q, kn, vn, nkp, nvp, bt, pos, **kw)
+    assert np.isfinite(np.asarray(io_)).all()
+    np.testing.assert_allclose(np.asarray(io_), np.asarray(ro),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(ikp), np.asarray(rkp),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(ivp), np.asarray(rvp),
                                atol=2e-5, rtol=2e-5)
 
 

@@ -6,8 +6,8 @@ rules (block tiling, VMEM limits, supported ops).  These tests lower and
 compile each kernel for a v5e described by ``get_topology_desc`` and
 check that the program really holds a Mosaic kernel
 (``tpu_custom_call``).  Shapes are yi-6b decode/prefill widths (kv heads
-4, 8 query heads per kv head, head_dim 128) and xlstm-125m for the
-linear scan.  The topology is described inside a fixture, never at
+4, 8 query heads per kv head, head_dim 128), yi-34b's 8 kv heads of 7
+for the fused decode, and xlstm-125m for the linear scan.  The topology is described inside a fixture, never at
 import, and every test skips where it cannot be described.
 """
 import os
@@ -58,9 +58,9 @@ def _compile_has_kernel(fn, *args):
     return compiled
 
 
-def _pool(dev, page, dtype, nb):
+def _pool(dev, page, dtype, nb, hkv=HKV):
     n = SLOTS * nb + 1                      # + sink page
-    return (_sds((n, HKV, page, D), dtype, dev),
+    return (_sds((n, hkv, page, D), dtype, dev),
             _sds((SLOTS, nb), jnp.int32, dev))
 
 
@@ -73,14 +73,15 @@ def test_paged_attention_grouped_compiles(one_chip):
     _compile_has_kernel(paged_attention_grouped, q, pool, pool, bt, lens)
 
 
+@pytest.mark.parametrize("hkv,g", [(HKV, G), (8, 7)])   # yi-6b, yi-34b
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_fused_paged_decode_grouped_compiles(one_chip, kv):
+def test_fused_paged_decode_grouped_compiles(one_chip, kv, hkv, g):
     from repro.kernels.paged_attention import fused_paged_decode_grouped
     page = 16 if kv == "bf16" else 32       # int8 tiles are (32, 128)
     dtype = jnp.bfloat16 if kv == "bf16" else jnp.int8
-    pool, bt = _pool(one_chip, page, dtype, MAX_SEQ // page)
-    q = _sds((SLOTS, HKV, G, D), jnp.bfloat16, one_chip)
-    fresh = _sds((SLOTS, HKV, D), jnp.bfloat16, one_chip)
+    pool, bt = _pool(one_chip, page, dtype, MAX_SEQ // page, hkv)
+    q = _sds((SLOTS, hkv, g, D), jnp.bfloat16, one_chip)
+    fresh = _sds((SLOTS, hkv, D), jnp.bfloat16, one_chip)
     pos = _sds((SLOTS,), jnp.int32, one_chip)
     if kv == "int8":
         sc = _sds(pool.shape[:-1], jnp.float32, one_chip)
