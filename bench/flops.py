@@ -1,6 +1,8 @@
 """Operations and bytes each unit of work needs, from its shapes: the
 model's FLOPs per token and per step, and each attention kernel's FLOPs
-and bytes per call.  ``dims`` comes from a configuration file.
+and bytes per call, for the dense Llama layer (``Dims``, from a
+configuration file; ``arch/llama.py`` counts through these), and the
+roofline every architecture's kernels are read against.
 
 Counting rules: a multiply-add is 2 FLOPs; a token at position ``p``
 attends ``p + 1`` keys (itself included); the LM head runs once per

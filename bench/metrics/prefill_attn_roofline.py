@@ -1,8 +1,8 @@
 """The paged prefill attention kernel's share of its roofline: the least
 time its calls in the traced window could take (per call, the larger of
 FLOPs over peak and bytes over HBM bandwidth, at the suffix's true length
-and offset; bench/flops.py) over their device time in the trace.  One
-call per layer per prefill."""
+and offset; the architecture module's ``prefill_attn_calls``) over their
+device time in the trace."""
 from bench import flops as F
 from bench import trace as T
 from bench.metrics import _steps
@@ -13,6 +13,7 @@ def read(run):
     calls = [c for t in run.ticks for c in t.prefills]
     if not n or not calls:
         return None
-    best = sum(F.roofline_s(*F.prefill_attn_call(run.dims, ln, off),
-                            run.peak) for ln, off in calls) * run.dims.layers
+    best = sum(F.roofline_s(flops, nbytes, run.peak) * k
+               for ln, off in calls
+               for flops, nbytes, k in run.arch.prefill_attn_calls(ln, off))
     return 100.0 * best / (ns * 1e-9)

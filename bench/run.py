@@ -117,13 +117,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     gap over the same served sequences."""
     jax = _setup_jax()
     from bench import driver as D
-    from bench import flops as F
     from bench import peaks as P
     from bench import reference as R
     from bench import spec as S
     from bench import trace as TRC
     from bench import traffic as TR
-    from bench import weights as W
     from bench import window as WIN
     from repro.backend import compat
     from repro.models import build_model
@@ -134,8 +132,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         f"{dev.device_kind}; seed {seed}; window {seconds}s; trace {trace}; "
         f"compile cache {compat.use_compile_cache()}")
 
-    model = build_model(S.model_config(conf))
-    params = W.program_params(conf, seed, model)
+    arch = S.architecture(conf)
+    model = build_model(arch.program_config())
+    params = arch.program_params(seed, model)
     jax.block_until_ready(params)
     eng = D.make_engine(model, params, st)
     cows = D.warm(eng, mix, st, conf["vocab_size"], seed)
@@ -190,7 +189,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     del eng, params
     gc.collect()
     t_ref = time.perf_counter()
-    gap = R.gaps(conf, seed, rows, control=control) if rows else None
+    gap = R.gaps(arch, seed, rows, control=control) if rows else None
     log(f"reference: {time.perf_counter() - t_ref:.3f}s over "
         f"{len(rows)} finished requests from "
         f"{len({r.slot for r in drv.records if r.done})} slots"
@@ -204,7 +203,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:
         run = Run(cell=cell, window=win, stats=stats, trace=ev,
-                  ticks=drv.ticks, dims=F.Dims.of(conf),
+                  ticks=drv.ticks, arch=arch,
                   peak=P.peak(dev.device_kind))
         for m in cell.per_layer:
             v = S.reader(m["name"])(run)

@@ -2,30 +2,27 @@
 configuration and its traffic mix; each has a data file of its own under
 ``bench/`` (``configs/<config>.json``, ``traffic/<mix>.json``,
 ``cells/<cell>.json``), and each per-layer metric a reader in
-``metrics/<metric>.py``.  Adding a cell, a configuration, a mix or a
-metric adds files; nothing here changes."""
+``metrics/<metric>.py``.  A configuration file names its architecture by
+its published ``model_type``, and ``arch/<model_type>.py`` gives what
+the harness needs of that architecture (``INTERFACE``): the program's
+configuration and weights, the plain reference, and the FLOP and byte
+counts.  Adding a cell, a mix or a metric adds its files; adding a
+configuration adds ``configs/<config>.json``, and for a new architecture
+``arch/<model_type>.py`` and, where the program needs one, an entry in
+its ``REGISTRY``.  Nothing here changes."""
 from __future__ import annotations
 
-import dataclasses
 import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
+from functools import partial
+from types import SimpleNamespace
 from typing import Any, Dict, List
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
-
-# published (Hugging Face ``config.json``) keys -> the program's ModelConfig
-HF_TO_PROGRAM = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-}
 
 
 def load_json(path: str) -> Dict[str, Any]:
@@ -70,19 +67,44 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         per_layer=[m for m in bm["per_layer"] if _applies(m, name)])
 
 
-def model_config(conf: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file: the
-    registry entry named by ``registry``, with every published size of the
-    file written over it (so the file, not the registry, is what runs)."""
-    from repro.configs import REGISTRY
-    base = REGISTRY[conf["registry"]]
-    sizes = {HF_TO_PROGRAM[k]: conf[k] for k in HF_TO_PROGRAM}
-    dtype = conf["torch_dtype"]
-    return dataclasses.replace(base, name=conf["name"], dtype=dtype,
-                               head_dim=sizes["d_model"] // sizes["num_heads"],
-                               param_dtype=dtype,
-                               tie_embeddings=conf["tie_word_embeddings"],
-                               **sizes)
+ARCH_DIR = os.path.join(BENCH_DIR, "arch")
+# what every ``arch/<model_type>.py`` gives, each taking the
+# configuration file's dict first
+INTERFACE = ("program_config", "program_params", "hidden", "final",
+             "decode_token_flops", "prefill_flops", "fused_decode_calls",
+             "prefill_attn_calls", "tiny")
+
+
+def architectures() -> List[str]:
+    """The ``model_type``s that have a module under ``arch/``."""
+    return sorted(f[:-3] for f in os.listdir(ARCH_DIR)
+                  if f.endswith(".py") and not f.startswith("_"))
+
+
+def architecture(conf: Dict[str, Any]) -> SimpleNamespace:
+    """The module ``arch/<model_type>.py`` of a configuration file, bound
+    to it: ``arch.f(*args)`` is ``module.f(conf, *args)`` for each name
+    of ``INTERFACE``; ``arch.module`` is the module itself."""
+    known = architectures()
+    kind = conf.get("model_type")
+    if kind not in known:
+        raise SystemExit(f"configuration {conf.get('name')!r} has model_type "
+                         f"{kind!r}; bench/arch/ has modules for {known}")
+    name = "bench_arch_" + kind
+    mod = sys.modules.get(name)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(ARCH_DIR, kind + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        # one module object per process, so its jitted functions (and
+        # their compiled programs) are shared by every caller
+        sys.modules[name] = mod
+    missing = [f for f in INTERFACE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"bench/arch/{kind}.py lacks {missing}")
+    return SimpleNamespace(module=mod, conf=conf, **{
+        f: partial(getattr(mod, f), conf) for f in INTERFACE})
 
 
 def reader(metric: str):

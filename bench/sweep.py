@@ -51,12 +51,12 @@ def main(argv=None) -> int:
         return 1
     from bench import driver as D
     from bench import traffic as TR
-    from bench import weights as W
     from bench import window as WIN
     from repro.models import build_model
     conf, mix, st = cell.config, cell.traffic, cell.settings
-    model = build_model(S.model_config(conf))
-    params = W.program_params(conf, args.seed, model)
+    arch = S.architecture(conf)
+    model = build_model(arch.program_config())
+    params = arch.program_params(args.seed, model)
     eng = D.make_engine(model, params, st)
     D.warm(eng, mix, st, conf["vocab_size"], args.seed)
     knee = None

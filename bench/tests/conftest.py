@@ -14,19 +14,16 @@ for p in (ROOT, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-TINY = dict(hidden_size=64, intermediate_size=128, vocab_size=256,
-            num_attention_heads=4, num_key_value_heads=2,
-            num_hidden_layers=2)
-
 
 def shrink(cell_name, dtype="float32", **settings):
-    """``cell_name`` at a size the CPU runs in seconds: tiny widths, short
-    prompts and outputs, four slots; the cell's own sampling of the
-    check.  In float32 the program and the reference agree to about 1e-5
-    here, so the stand-in limit on the widest gap is 0.05."""
+    """``cell_name`` at a size the CPU runs in seconds: its architecture
+    module's tiny widths, short prompts and outputs, four slots; the
+    cell's own sampling of the check.  In float32 the program and the
+    reference agree to about 1e-5 here, so the stand-in limit on the
+    widest gap is 0.05."""
     from bench import spec
     cell = spec.load_cell(cell_name)
-    conf = dict(cell.config, torch_dtype=dtype, **TINY)
+    conf = spec.architecture(dict(cell.config, torch_dtype=dtype)).tiny()
     mix = dict(cell.traffic, ramp_s=1,
                prompt=dict(cell.traffic["prompt"], median=32, min=8, max=64),
                output=dict(cell.traffic["output"], median=8, min=4, max=16))
